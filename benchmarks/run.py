@@ -8,7 +8,8 @@ scoped under ``dispatch.force_backend`` so every registry-dispatched op
 then writes the per-backend rows plus the ``(op, backend)`` pairs that
 actually resolved — the paper-style microbenchmark comparison across
 software stacks, attributable to the implementation that really ran
-(an unsupported preference degrades to capability-ranked auto).
+(a registered backend that cannot serve a call raises; an op family
+without that backend resolves by capability-ranked auto).
 
 ``--policy adm/pre/evi,...`` sweeps serving-policy triples the same way:
 each triple is scoped under ``repro.serving.policy.force_policies`` so every
@@ -31,7 +32,10 @@ sweeps ``off,ngram`` only).
 
 ``--devices 1,2,4`` sweeps host device counts: the XLA device count is
 fixed at first jax init, so each count re-runs the selected modules in a
-SUBPROCESS under ``XLA_FLAGS=--xla_force_host_platform_device_count=<n>``.
+SUBPROCESS under ``XLA_FLAGS=--xla_force_host_platform_device_count=<n>``
+and ``JAX_PLATFORMS=cpu``.  Those are CPU devices, so on a host with TPU
+chips the sweep refuses to start: the children would contend for the chip
+or time the CPU on an accelerator host.
 With > 1 device the llm_e2e scenario engines build a serving mesh
 (``repro.launch.mesh.make_serving_mesh``) and run the sharded fused step
 (docs/sharded_serving.md); every ``--json`` record and row is stamped with
@@ -73,6 +77,7 @@ import traceback
 
 from benchmarks import common
 from repro.core import dispatch
+from repro.launch import runtime
 from repro.serving import policy as policy_lib
 from repro.serving import spec as spec_lib
 
@@ -162,6 +167,12 @@ def _sweep_devices(args) -> int:
     whose records the parent merges with a ``devices`` stamp on every
     record and row.
     """
+    if runtime.tpu_chips_attached():
+        raise SystemExit(
+            "--devices sweeps CPU host devices in child processes; this host "
+            "has TPU chips, which one process at a time may hold. Run the "
+            "sharded engine in one process instead (python chip_smoke.py "
+            "--chips 4, or python -m repro.launch.serve --devices N).")
     counts = []
     for c in args.devices.split(","):
         try:
@@ -191,6 +202,7 @@ def _sweep_devices(args) -> int:
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                             + f" --xla_force_host_platform_device_count={n}"
                             ).strip()
+        env["JAX_PLATFORMS"] = "cpu"
         # Engine-building modules (llm_e2e) opt into a serving mesh ONLY on
         # this explicit signal — ambient multi-device hosts keep running the
         # single-device engine so --backend sweeps stay comparable.
@@ -258,6 +270,7 @@ def main() -> None:
     args = p.parse_args()
     if args.devices is not None:
         raise SystemExit(_sweep_devices(args))
+    runtime.enable_compile_cache()
     mods = args.only.split(",") if args.only else MODULES
     backends = args.backend.split(",") if args.backend else [None]
     policies = (_parse_policy_triples(args.policy) if args.policy

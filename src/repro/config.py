@@ -330,8 +330,8 @@ class ServeConfig:
     use_block_list: bool = True    # paper technique ON (False = padded baseline)
     # Operator-backend preference for registry-dispatched ops (the config
     # level of repro.core.dispatch precedence: overridden by explicit args,
-    # force_backend scopes and REPRO_BACKEND; falls back to capability-ranked
-    # auto when the named backend can't serve this platform/call).
+    # force_backend scopes and REPRO_BACKEND; a registered backend that
+    # can't serve this platform/call raises BackendUnavailableError).
     backend: str = "auto"          # auto | ref | xla | pallas | pallas_interpret
     # Serving-policy preferences (the config level of repro.serving.policy
     # precedence: overridden by explicit ctor args and force_policies scopes;
@@ -364,8 +364,8 @@ class ServeConfig:
     q_chunk: int = 16
     # Which attention op family the fused step dispatches per layer:
     # "ragged" = paged_attention_ragged (ONE launch for prefill chunks +
-    # decode lanes via cu_q_lens/cu_kv_lens metadata over the fused
-    # head-interleaved KV pool), "chunked" = the PR-6 token-lane path on
+    # decode lanes via cu_q_lens/cu_kv_lens metadata over the fused KV
+    # pool), "chunked" = the PR-6 token-lane path on
     # split views of the same pool.  Greedy streams are bit-identical.
     attn_impl: str = "ragged"      # ragged | chunked
     # Ragged-kernel tunables (paged_attention_ragged op family,

@@ -1,5 +1,5 @@
 """smollm-360m [dense] — 32L d_model=960 15H (GQA kv=5) d_ff=2560
-vocab=49152. llama-arch small. [hf:HuggingFaceTB/SmolLM-135M family; hf]"""
+vocab=49152. llama-arch small. [hf:HuggingFaceTB/SmolLM-360M, config.json]"""
 from repro.config import AttentionConfig, ModelConfig, register
 
 CONFIG = register(ModelConfig(
@@ -15,5 +15,5 @@ CONFIG = register(ModelConfig(
     ),
     tie_embeddings=True,
     act="silu",
-    source="hf:HuggingFaceTB/SmolLM-135M; hf",
+    source="hf:HuggingFaceTB/SmolLM-360M; config.json",
 ))

@@ -275,12 +275,13 @@ def paged_attention_ragged(q, kv_pool, block_list, block_req, block_pos,
                            cu_q_lens, cu_kv_lens, seq_slot,
                            *, sm_scale: Optional[float] = None):
     """One ragged launch for mixed prefill-chunk + decode lanes over the
-    FUSED head-interleaved KV pool (the ``ref`` oracle of the
-    ``paged_attention_ragged`` family).
+    FUSED KV pool (the ``ref`` oracle of the ``paged_attention_ragged``
+    family).
 
     q          (T, H, HD)   flat token lanes, sequences contiguous in lane
                             order (decode lanes and prompt-chunk lanes mixed)
-    kv_pool    (NB, BS, 2*KV, HD)  fused ``[K0,V0,K1,V1,...]`` pool layer
+    kv_pool    (NB, KV, BS, 2*HD)  fused pool layer, K and V side by side
+                            on the minor axis
                             (:func:`repro.core.paged_kv.make_fused_pool`)
     block_*    (Tb,)        flat BlockList keyed by slot id, as in
                             :func:`paged_attention_chunked`

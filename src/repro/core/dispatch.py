@@ -35,9 +35,12 @@ Resolution precedence (highest wins)
 4. a config hint (e.g. ``ServeConfig.backend``) passed by the caller;
 5. capability-ranked auto: the supported implementation with the highest rank.
 
-Levels 2–4 are *preferences*: if the preferred backend is unavailable for this
-call the resolver falls back to auto ranking (so ``REPRO_BACKEND=pallas`` on a
-CPU host degrades to the best supported implementation instead of crashing).
+Levels 2–4 are *preferences* only in that a family which has no
+implementation under the preferred name resolves by auto ranking.  A preferred
+backend that IS registered but whose capability predicate rejects the call
+raises :class:`BackendUnavailableError`, exactly like an explicit request: so
+``REPRO_BACKEND=pallas`` on a host where JAX found no TPU fails loudly instead
+of serving with another backend on the CPU.
 Every resolution is appended to the active :func:`record_resolutions` scope so
 benchmarks can attribute numbers to the implementation that actually ran.
 
@@ -295,8 +298,8 @@ class OpFamily:
                     f"(have {self.backends()})")
             if not impl.supports(spec):
                 raise BackendUnavailableError(
-                    f"{self.name}: backend {backend!r} does not support this "
-                    f"call on platform {spec.platform!r}")
+                    f"{self.name}: backend {backend!r} rejects this call "
+                    f"(platform {spec.platform!r}, or its shapes)")
             # The resolved name must round-trip an explicit request — this is
             # the single-resolver guarantee that killed the old double
             # dispatch (pallas request silently re-deciding to ref).
@@ -310,10 +313,14 @@ class OpFamily:
             if pref in _AUTO_NAMES:
                 continue
             impl = self._impls.get(pref)
-            if impl is not None and impl.supports(spec):
-                self._note(impl)
-                return impl
-            # Preference unavailable for this call: fall through to auto.
+            if impl is None:
+                continue          # no such implementation in this family
+            if not impl.supports(spec):
+                raise BackendUnavailableError(
+                    f"{self.name}: preferred backend {pref!r} rejects this "
+                    f"call (platform {spec.platform!r}, or its shapes)")
+            self._note(impl)
+            return impl
 
         for impl in self.impls():                      # 5. ranked auto
             if impl.supports(spec):

@@ -62,9 +62,11 @@ Per scheduling step the allocator also renders the device layouts:
     BlockList its pool shard can serve, with LOCAL pool indices
     (docs/sharded_serving.md).
 
-Device side: the pool is a dense array (num_blocks, block_size, KV, HD) per
-layer (stacked over layers for scan). ``append_to_pool`` writes one new token
-per active request into its current block/offset.
+Device side: the serving pool is one dense fused array (num_blocks, KV,
+block_size, 2*HD) per layer (stacked over layers for scan);
+``append_to_fused_pool`` writes one new token per lane into its current
+block/offset.  Split (num_blocks, block_size, KV, HD) K and V pools remain
+for the decode-only path (``append_to_pool``).
 """
 from __future__ import annotations
 
@@ -105,8 +107,8 @@ class HostBlock:
     """One demoted KV block staged in host memory.
 
     ``data`` is filled lazily by the engine's tier drain (a device→host copy
-    of the block's slice per pool channel — ONE fused ``kv`` slice with the
-    head-interleaved layout, (k, v) slices with split pools); ``stats``
+    of the block's slice per pool channel — ONE fused ``kv`` slice, or
+    (k, v) slices with split pools); ``stats``
     carries the block's eviction evidence across the tier round-trip so a
     promoted block keeps its history.
     """
@@ -795,51 +797,63 @@ def make_pool(num_layers: int, num_blocks: int, block_size: int,
 
 def make_fused_pool(num_layers: int, num_blocks: int, block_size: int,
                     num_kv: int, head_dim: int, dtype=jnp.bfloat16):
-    """ONE head-interleaved KV buffer: ``[K0, V0, K1, V1, ...]`` on the head
-    axis (docs/ragged_kernel.md).
+    """ONE KV buffer, head-major per page, K and V side by side on the
+    minor axis (docs/ragged_kernel.md).
 
-    Shape (L, NB, BS, 2*KV, HD) — K and V of each kv-head are adjacent, so
-    every whole-buffer move (CoW block copy, tier demote/promote, disagg
-    handoff, the kernel's HBM->VMEM page DMA) is ONE transfer instead of two.
-    ``fused_kv_views`` recovers (k, v) views for math written against split
-    pools; ``fuse_kv_heads`` interleaves fresh per-token K/V for the append.
+    Shape (L, NB, KV, BS, 2*HD): lanes ``[:HD]`` of a page row hold K and
+    ``[HD:]`` hold V.  Every whole-block move (CoW block copy, tier
+    demote/promote, disagg handoff, the kernel's HBM->VMEM page DMA) is ONE
+    transfer instead of two, and a page's two minor dims are (BS, 2*HD):
+    a head_dim-64 page is made of whole 128-lane rows with the page size on
+    the sublanes, the tiling the TPU's DMA engine moves and the HBM layout
+    pads nothing of.  ``fused_kv_views`` recovers split (k, v) pools for
+    math written against them; ``append_to_fused_pool`` writes fresh
+    per-token K/V.
     """
-    shape = (num_layers, num_blocks, block_size, 2 * num_kv, head_dim)
+    shape = (num_layers, num_blocks, num_kv, block_size, 2 * head_dim)
     return jnp.zeros(shape, dtype)
 
 
 def fused_kv_views(pool):
-    """Split-view shim over a fused pool: ``(..., 2*KV, HD) -> k, v``.
+    """Split pools from a fused one: ``(..., KV, BS, 2*HD) -> k, v`` of
+    shape ``(..., BS, KV, HD)``, the split-pool layout.
 
-    Pure reshape + index (no data movement until consumed), valid for any
-    leading dims — a whole layer stack, one layer, or a single VMEM page
-    tile inside a kernel.  The views hold exactly the values a split pool
-    would, so math running on them is bit-identical to the split layout.
+    Valid for any leading dims — a whole layer stack, one layer, or a
+    single page.  The views hold exactly the values a split pool would, so
+    math running on them is bit-identical to the split layout.
     """
-    *lead, kv2, hd = pool.shape
-    r = pool.reshape(*lead, kv2 // 2, 2, hd)
-    return r[..., 0, :], r[..., 1, :]
+    hd = pool.shape[-1] // 2
+    split = jnp.swapaxes(pool, -3, -2)
+    return split[..., :hd], split[..., hd:]
 
 
-def fuse_kv_heads(k_new, v_new):
-    """Interleave per-token K/V ``(..., KV, HD) x2 -> (..., 2*KV, HD)``.
-
-    Inverse of :func:`fused_kv_views` on the head axis: the result's head
-    order is ``[K0, V0, K1, V1, ...]``, ready for ONE ``append_to_pool``
-    scatter into a fused pool.
+def fuse_kv_heads(pool_k, pool_v):
+    """Inverse of :func:`fused_kv_views`: split pools ``(..., BS, KV, HD)
+    x2 -> (..., KV, BS, 2*HD)``, K in the low lanes and V in the high ones.
     """
-    *lead, kv, hd = k_new.shape
-    return jnp.stack([k_new, v_new], axis=-2).reshape(*lead, 2 * kv, hd)
+    return jnp.swapaxes(jnp.concatenate([pool_k, pool_v], axis=-1), -3, -2)
 
 
 def append_to_pool(pool_layer, kv_new, slots):
-    """Write one token per request into a single layer's pool.
+    """Write one token per request into a single layer's split pool.
 
     pool_layer (NB, BS, KV, HD); kv_new (B, KV, HD); slots (B, 2) [block, off].
     Out-of-range slots (e.g. (NB, 0) on non-owning model ranks of a sharded
     pool) are dropped — this is how sharded writes stay shard-local.
     """
     return pool_layer.at[slots[:, 0], slots[:, 1]].set(
+        kv_new.astype(pool_layer.dtype), mode="drop")
+
+
+def append_to_fused_pool(pool_layer, k_new, v_new, slots):
+    """Write one token per lane into a single layer's fused pool.
+
+    pool_layer (NB, KV, BS, 2*HD); k_new/v_new (T, KV, HD); slots (T, 2)
+    [block, off].  Out-of-range slots are dropped, as in
+    :func:`append_to_pool`.
+    """
+    kv_new = jnp.concatenate([k_new, v_new], axis=-1)        # (T, KV, 2*HD)
+    return pool_layer.at[slots[:, 0], :, slots[:, 1]].set(
         kv_new.astype(pool_layer.dtype), mode="drop")
 
 

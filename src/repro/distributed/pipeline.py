@@ -60,10 +60,8 @@ def pipeline_forward(layer_fn: Callable[[Any, jnp.ndarray], jnp.ndarray],
         return (nxt, outs), None
 
     # carries become device-varying through ppermute; mark them as such
-    # (pre-0.5 jax has no pvary — everything inside shard_map is varying)
-    pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
-    buf0 = pvary(jnp.zeros_like(x_micro[0]), (axis,))
-    outs0 = pvary(jnp.zeros_like(x_micro), (axis,))
+    buf0 = jax.lax.pcast(jnp.zeros_like(x_micro[0]), (axis,), to="varying")
+    outs0 = jax.lax.pcast(jnp.zeros_like(x_micro), (axis,), to="varying")
     (_, outs), _ = jax.lax.scan(tick, (buf0, outs0), jnp.arange(S + M - 1))
     # broadcast the last stage's outputs to every rank (replicated result);
     # a production loss would instead consume outs on the last stage only

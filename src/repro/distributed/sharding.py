@@ -18,8 +18,20 @@ from typing import Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
+
+
+def auto_mesh(shape, axes, **kw):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``.
+
+    Every mesh in this repo is built here: its code leaves the partitioning
+    of ordinary array code to GSPMD and indexes sharded results freely,
+    which ``jax.make_mesh``'s default ``Explicit`` axes forbid.
+    """
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         **kw)
+
 
 # weight-name classification
 _COL = {"wq", "wk", "wv", "w_gate", "w_up", "w_in", "in_proj", "wr", "w1",

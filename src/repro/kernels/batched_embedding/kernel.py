@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
 
 
 def _embed_kernel(global_ids, row_ref, o_ref, acc_ref, *, pool_l: int):
@@ -62,7 +61,7 @@ def batched_embedding_pallas(big_table, global_ids, pool_l: int, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_bags, D), big_table.dtype),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(global_ids, big_table)

@@ -13,7 +13,6 @@ import jax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
 
 
 def _gather_kernel(idx, row_ref, o_ref):
@@ -39,7 +38,7 @@ def gather_pallas(table, idx, *, interpret: bool = True):
         _gather_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, D), table.dtype),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(idx, table)
@@ -61,7 +60,7 @@ def scatter_pallas(table, idx, src, *, interpret: bool = True):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, D), table.dtype),
         input_output_aliases={2: 0},     # table buffer updated in place
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(idx, src, table)

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 NEG_INF = -1e30
 
 
@@ -52,46 +50,28 @@ def _paged_kernel(
 
     @pl.when(jnp.logical_not(is_pad))
     def _step():
-        H, hd = q_ref.shape[1], q_ref.shape[2]
-        G = H // num_kv
         pos = block_pos[t] * bs + jax.lax.broadcasted_iota(
-            jnp.int32, (1, bs), 1)[0]
+            jnp.int32, (1, bs), 1)                     # (1, bs)
         valid = pos < seq_lens[jnp.minimum(req, num_reqs - 1)]
-
-        for kv in range(num_kv):                       # static small loop
-            q = q_ref[0, kv * G:(kv + 1) * G, :]       # (G, hd)
-            k = k_ref[0, :, kv, :]                     # (bs, hd)
-            v = v_ref[0, :, kv, :]
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            s = s * sm_scale                           # (G, bs)
-            s = jnp.where(valid[None, :], s, NEG_INF)
-            m_prev = m_ref[kv, :G]
-            m_new = jnp.maximum(m_prev, s.max(axis=-1))
-            corr = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new[:, None])
-            p = jnp.where(valid[None, :], p, 0.0)
-            l_new = l_ref[kv, :G] * corr + p.sum(axis=-1)
-            pv = jax.lax.dot_general(p.astype(v.dtype), v,
-                                     (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            acc_ref[kv * G:(kv + 1) * G, :] = (
-                acc_ref[kv * G:(kv + 1) * G, :] * corr[:, None] + pv)
-            m_ref[kv, :G] = m_new
-            l_ref[kv, :G] = l_new
-
-        # Rewrite the running normalized output; the last block of this
+        # Rewrites the running normalized output; the last block of this
         # request leaves the final value.
-        l = jnp.maximum(l_ref[:, :G].reshape(H, 1), 1e-30)
-        o_ref[0, :, :] = (acc_ref[...] / l).astype(o_ref.dtype)
+        _flash_update(q_ref, lambda kv: (k_ref[0, :, kv, :],
+                                         v_ref[0, :, kv, :]),
+                      o_ref, acc_ref, m_ref, l_ref, valid, num_kv=num_kv,
+                      sm_scale=sm_scale)
 
 
 def paged_attention_pallas(q, pool_k, pool_v, block_list, block_req,
                            block_pos, seq_lens, *, sm_scale=None,
                            interpret: bool = True):
-    """q (B,H,hd); pools (NB,BS,KV,hd); flat BlockList arrays (T,)."""
+    """q (B,H,hd); pools (NB,BS,KV,hd); flat BlockList arrays (T,).
+
+    Each request's query heads are laid out per KV head outside the kernel
+    ((B, KV, G, hd)), so every matmul inside is a 2-D (G, hd) x (hd, bs).
+    """
     B, H, hd = q.shape
     NB, BS, KV, _ = pool_k.shape
+    G = H // KV
     T = block_list.shape[0]
     scale = float(sm_scale if sm_scale is not None else hd ** -0.5)
 
@@ -100,91 +80,125 @@ def paged_attention_pallas(q, pool_k, pool_v, block_list, block_req,
 
     # index maps take (grid ids, *prefetched scalars)
     def q_map(t, bl, br, bp, sl):
-        return (jnp.minimum(br[t], B - 1), 0, 0)
+        return (jnp.minimum(br[t], B - 1), 0, 0, 0)
 
     def kv_map(t, bl, br, bp, sl):
         return (bl[t], 0, 0, 0)
-
-    def o_map(t, bl, br, bp, sl):
-        return (jnp.minimum(br[t], B - 1), 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(T,),
         in_specs=[
-            pl.BlockSpec((1, H, hd), q_map),
+            pl.BlockSpec((1, KV, G, hd), q_map),
             pl.BlockSpec((1, BS, KV, hd), kv_map),
             pl.BlockSpec((1, BS, KV, hd), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, H, hd), o_map),
+        out_specs=pl.BlockSpec((1, KV, G, hd), q_map),
         scratch_shapes=[
-            pltpu.VMEM((H, hd), jnp.float32),
-            pltpu.VMEM((KV, max(8, H // KV)), jnp.float32),
-            pltpu.VMEM((KV, max(8, H // KV)), jnp.float32),
+            pltpu.VMEM((KV, G, hd), jnp.float32),
+            pltpu.VMEM((KV, G, 1), jnp.float32),
+            pltpu.VMEM((KV, G, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
-        compiler_params=compat.CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(block_list, block_req, block_pos, seq_lens, q, pool_k, pool_v)
+    )(block_list, block_req, block_pos, seq_lens, q.reshape(B, KV, G, hd),
+      pool_k, pool_v)
+    return out.reshape(B, H, hd)
 
 
-def _chunked_flash_update(q_ref, k_blk, v_blk, o_ref, acc_ref, m_ref, l_ref,
-                          valid, *, num_kv: int, sm_scale: float):
-    """One online-softmax update of a query chunk against one KV block tile.
+def _flash_update(q_ref, page, o_ref, acc_ref, m_ref, l_ref, valid, *,
+                  num_kv: int, sm_scale: float):
+    """One online-softmax update of a query tile against one KV page.
 
-    ``k_blk``/``v_blk`` are the (bs, KV, hd) tile VALUES for this BlockList
-    entry — loaded either by the BlockSpec pipeline (``_chunked_kernel``) or
-    from the manual multi-buffered DMA ring (``_chunked_kernel_prefetch``).
-    Shared so the two DMA strategies cannot drift numerically.
+    ``q_ref``/``o_ref`` blocks are (1, KV, R, hd): the R query rows of each
+    KV head's group, laid out outside the kernel so no head group is
+    reshaped in VMEM (Mosaic refuses a (TQ, G, hd) -> (TQ*G, hd) cast when
+    G is not tile-aligned).  ``page(kv)`` returns that KV head's (bs, hd)
+    K and V values; ``valid`` is an (R, bs) or (1, bs) mask.  Scratch is
+    (KV, R, hd) for the accumulator and (KV, R, 1) for the running max and
+    sum.  Shared by all three kernels so their math cannot drift.
     """
-    TQ, H, hd = q_ref.shape
-    G = H // num_kv
     for kv in range(num_kv):                       # static small loop
-        q = q_ref[:, kv * G:(kv + 1) * G, :]       # (TQ, G, hd)
-        k = k_blk[:, kv, :]                        # (bs, hd)
-        v = v_blk[:, kv, :]
-        s = jax.lax.dot_general(q, k, (((2,), (1,)), ((), ())),
+        q = q_ref[0, kv]                           # (R, hd)
+        k, v = page(kv)                            # (bs, hd) each
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        s = s * sm_scale                           # (TQ, G, bs)
-        s = jnp.where(valid[:, None, :], s, NEG_INF)
-        m_prev = m_ref[:, kv * G:(kv + 1) * G]     # (TQ, G)
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
+        s = s * sm_scale                           # (R, bs)
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_ref[kv]                         # (R, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, :, None])
-        p = jnp.where(valid[:, None, :], p, 0.0)
-        l_new = l_ref[:, kv * G:(kv + 1) * G] * corr + p.sum(axis=-1)
+        p = jnp.exp(s - m_new)
+        p = jnp.where(valid, p, 0.0)
+        l_new = l_ref[kv] * corr + p.sum(axis=-1, keepdims=True)
         pv = jax.lax.dot_general(p.astype(v.dtype), v,
-                                 (((2,), (0,)), ((), ())),
+                                 (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        acc_ref[:, kv * G:(kv + 1) * G, :] = (
-            acc_ref[:, kv * G:(kv + 1) * G, :] * corr[:, :, None] + pv)
-        m_ref[:, kv * G:(kv + 1) * G] = m_new
-        l_ref[:, kv * G:(kv + 1) * G] = l_new
+        acc = acc_ref[kv] * corr + pv
+        acc_ref[kv] = acc
+        m_ref[kv] = m_new
+        l_ref[kv] = l_new
+        # Rewrite the running normalized output; the last page of the tile
+        # leaves the final value.
+        o_ref[0, kv] = (acc / jnp.maximum(l_new, 1e-30)).astype(o_ref.dtype)
 
-    # Rewrite the running normalized output; the last BlockList entry
-    # leaves the final value for this query chunk.
-    l = jnp.maximum(l_ref[...], 1e-30)             # (TQ, H)
-    o_ref[...] = (acc_ref[...] / l[:, :, None]).astype(o_ref.dtype)
+
+def _group_lanes(q, token_req, token_pos, num_kv: int, tq: int):
+    """Lay flat lanes out per KV head for the token-lane kernels.
+
+    q (Tp, H, hd) -> (Tp/tq, KV, G*tq, hd), rows ordered (group head, lane)
+    within each query tile; the lane metadata (Tp,) is repeated to match as
+    (Tp/tq, G*tq, 1), so the kernel's mask is built at the matmul's shape.
+    """
+    Tp, H, hd = q.shape
+    G = H // num_kv
+    n = Tp // tq
+    qg = q.reshape(n, tq, num_kv, G, hd).transpose(0, 2, 3, 1, 4)
+
+    def rep(x):
+        x = jnp.broadcast_to(x.reshape(n, 1, tq), (n, G, tq))
+        return x.reshape(n, G * tq, 1).astype(jnp.int32)
+
+    return (qg.reshape(n, num_kv, G * tq, hd), rep(token_req),
+            rep(token_pos))
+
+
+def _ungroup_lanes(o, T: int, tq: int):
+    """Inverse of :func:`_group_lanes` on the output: -> (T, H, hd)."""
+    n, KV, R, hd = o.shape
+    G = R // tq
+    o = o.reshape(n, KV, G, tq, hd).transpose(0, 3, 1, 2, 4)
+    return o.reshape(n * tq, KV * G, hd)[:T]
 
 
 def _chunked_valid_mask(block_req, block_pos, kv_lens, treq_ref, tpos_ref,
                         t, *, bs: int, num_reqs: int):
-    """(TQ, bs) ownership+causality+length mask for BlockList entry ``t``."""
+    """(R, bs) ownership+causality+length mask for BlockList entry ``t``.
+
+    ``treq_ref``/``tpos_ref`` are (1, R, 1) blocks of the grouped lane
+    metadata (:func:`_group_lanes`)."""
     req = block_req[t]
-    treq = treq_ref[:, 0]                          # (TQ,)
-    tpos = tpos_ref[:, 0]
+    treq = treq_ref[0]                             # (R, 1)
+    tpos = tpos_ref[0]
     key_pos = block_pos[t] * bs + jax.lax.broadcasted_iota(
-        jnp.int32, (1, bs), 1)[0]                  # (bs,)
+        jnp.int32, (1, bs), 1)                     # (1, bs)
     kvl = kv_lens[jnp.minimum(req, num_reqs - 1)]
-    lane_ok = (treq == req) & (treq < num_reqs)    # (TQ,)
-    return (lane_ok[:, None]
-            & (key_pos[None, :] <= tpos[:, None])   # causal
-            & (key_pos[None, :] < kvl))             # (TQ, bs)
+    lane_ok = (treq == req) & (treq < num_reqs)    # (R, 1)
+    return lane_ok & (key_pos <= tpos) & (key_pos < kvl)   # causal, length
+
+
+def _init_tile(acc_ref, m_ref, l_ref, o_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    # Lanes with no valid keys (padding, empty requests) must read 0.
+    o_ref[...] = jnp.zeros_like(o_ref)
 
 
 def _chunked_kernel(
@@ -211,19 +225,16 @@ def _chunked_kernel(
 
     @pl.when(t == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        # Lanes with no valid keys (padding, empty requests) must read 0.
-        o_ref[...] = jnp.zeros_like(o_ref)
+        _init_tile(acc_ref, m_ref, l_ref, o_ref)
 
     @pl.when(jnp.logical_not(is_pad))
     def _step():
         valid = _chunked_valid_mask(block_req, block_pos, kv_lens, treq_ref,
                                     tpos_ref, t, bs=bs, num_reqs=num_reqs)
-        _chunked_flash_update(q_ref, k_ref[0], v_ref[0], o_ref, acc_ref,
-                              m_ref, l_ref, valid, num_kv=num_kv,
-                              sm_scale=sm_scale)
+        _flash_update(q_ref, lambda kv: (k_ref[0, :, kv, :],
+                                         v_ref[0, :, kv, :]),
+                      o_ref, acc_ref, m_ref, l_ref, valid, num_kv=num_kv,
+                      sm_scale=sm_scale)
 
 
 def _chunked_kernel_prefetch(
@@ -265,10 +276,7 @@ def _chunked_kernel_prefetch(
 
     @pl.when(t == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        o_ref[...] = jnp.zeros_like(o_ref)
+        _init_tile(acc_ref, m_ref, l_ref, o_ref)
         for d in range(min(depth - 1, Tb)):       # warm-up: fill the ring
             start(jnp.int32(d))
 
@@ -285,9 +293,28 @@ def _chunked_kernel_prefetch(
     def _step():
         valid = _chunked_valid_mask(block_req, block_pos, kv_lens, treq_ref,
                                     tpos_ref, t, bs=bs, num_reqs=num_reqs)
-        _chunked_flash_update(q_ref, k_buf[slot], v_buf[slot], o_ref, acc_ref,
-                              m_ref, l_ref, valid, num_kv=num_kv,
-                              sm_scale=sm_scale)
+        _flash_update(q_ref, lambda kv: (k_buf[slot, :, kv, :],
+                                         v_buf[slot, :, kv, :]),
+                      o_ref, acc_ref, m_ref, l_ref, valid, num_kv=num_kv,
+                      sm_scale=sm_scale)
+
+
+def _pad_lanes(q, token_req, token_pos, tq: int, num_reqs: int):
+    """Pad flat lanes to a multiple of ``tq``; padding lanes get an
+    out-of-range owner so every key is masked."""
+    pad = (-q.shape[0]) % tq
+    if pad:
+        q = jnp.pad(q, ((0, pad), (0, 0), (0, 0)))
+        token_req = jnp.pad(token_req, (0, pad), constant_values=num_reqs)
+        token_pos = jnp.pad(token_pos, (0, pad))
+    return q, token_req, token_pos
+
+
+def _lane_scratch(KV: int, R: int, hd: int):
+    """Flash accumulator, running max and running sum for one query tile."""
+    return [pltpu.VMEM((KV, R, hd), jnp.float32),
+            pltpu.VMEM((KV, R, 1), jnp.float32),
+            pltpu.VMEM((KV, R, 1), jnp.float32)]
 
 
 def paged_attention_chunked_pallas(q, pool_k, pool_v, block_list, block_req,
@@ -323,36 +350,27 @@ def paged_attention_chunked_pallas(q, pool_k, pool_v, block_list, block_req,
         raise ValueError(f"prefetch_depth must be >= 0, got {depth}")
 
     tq = max(min(q_chunk, T), 1)
-    pad = (-T) % tq
-    if pad:
-        q = jnp.pad(q, ((0, pad), (0, 0), (0, 0)))
-        # Padding lanes get an out-of-range owner so every key is masked.
-        token_req = jnp.pad(token_req, (0, pad), constant_values=B)
-        token_pos = jnp.pad(token_pos, (0, pad))
-    Tp = T + pad
-    treq = token_req.reshape(Tp, 1).astype(jnp.int32)
-    tpos = token_pos.reshape(Tp, 1).astype(jnp.int32)
+    q, token_req, token_pos = _pad_lanes(q, token_req, token_pos, tq, B)
+    qg, treq, tpos = _group_lanes(q, token_req, token_pos, KV, tq)
+    n, _, R, _ = qg.shape
 
     # index maps take (grid ids, *prefetched scalars)
     def q_map(i, t, bl, br, bp, kvl):
-        return (i, 0, 0)
+        return (i, 0, 0, 0)
 
     def kv_map(i, t, bl, br, bp, kvl):
         return (bl[t], 0, 0, 0)
 
     def lane_map(i, t, bl, br, bp, kvl):
-        return (i, 0)
+        return (i, 0, 0)
 
     if depth >= 2:
         kernel = functools.partial(
             _chunked_kernel_prefetch, bs=BS, num_kv=KV, num_reqs=B,
             sm_scale=scale, depth=depth, num_blocks=NB)
         # Pools stay in HBM; the kernel rings its own page DMAs.
-        kv_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-        scratch = [
-            pltpu.VMEM((tq, H, hd), jnp.float32),
-            pltpu.VMEM((tq, H), jnp.float32),
-            pltpu.VMEM((tq, H), jnp.float32),
+        kv_spec = pl.BlockSpec(memory_space=pl.ANY)
+        scratch = _lane_scratch(KV, R, hd) + [
             pltpu.VMEM((depth, BS, KV, hd), pool_k.dtype),
             pltpu.VMEM((depth, BS, KV, hd), pool_v.dtype),
             pltpu.SemaphoreType.DMA((depth,)),
@@ -365,36 +383,32 @@ def paged_attention_chunked_pallas(q, pool_k, pool_v, block_list, block_req,
         kernel = functools.partial(_chunked_kernel, bs=BS, num_kv=KV,
                                    num_reqs=B, sm_scale=scale)
         kv_spec = pl.BlockSpec((1, BS, KV, hd), kv_map)
-        scratch = [
-            pltpu.VMEM((tq, H, hd), jnp.float32),
-            pltpu.VMEM((tq, H), jnp.float32),
-            pltpu.VMEM((tq, H), jnp.float32),
-        ]
+        scratch = _lane_scratch(KV, R, hd)
         semantics = ("parallel", "arbitrary")
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(Tp // tq, Tb),
+        grid=(n, Tb),
         in_specs=[
-            pl.BlockSpec((tq, H, hd), q_map),
+            pl.BlockSpec((1, KV, R, hd), q_map),
             kv_spec,
             kv_spec,
-            pl.BlockSpec((tq, 1), lane_map),
-            pl.BlockSpec((tq, 1), lane_map),
+            pl.BlockSpec((1, R, 1), lane_map),
+            pl.BlockSpec((1, R, 1), lane_map),
         ],
-        out_specs=pl.BlockSpec((tq, H, hd), q_map),
+        out_specs=pl.BlockSpec((1, KV, R, hd), q_map),
         scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Tp, H, hd), q.dtype),
-        compiler_params=compat.CompilerParams(
+        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics),
         interpret=interpret,
-    )(block_list, block_req, block_pos, kv_lens, q, pool_k, pool_v,
+    )(block_list, block_req, block_pos, kv_lens, qg, pool_k, pool_v,
       treq, tpos)
-    return out[:T]
+    return _ungroup_lanes(out, T, tq)
 
 
 def _ragged_kernel(
@@ -406,26 +420,29 @@ def _ragged_kernel(
     o_ref,
     # scratch
     acc_ref, m_ref, l_ref, kv_buf, kv_sem,
-    *, bs: int, num_kv: int, num_reqs: int, sm_scale: float, pages: int,
-    num_blocks: int,
+    *, bs: int, num_kv: int, head_dim: int, num_reqs: int, sm_scale: float,
+    pages: int, num_blocks: int,
 ):
-    """Ragged grid step over the FUSED head-interleaved pool.
+    """Ragged grid step over the FUSED pool.
 
     Grid is (num_q_tiles, num_page_groups): one step consumes ``pages``
     BlockList entries against one ``num_queries_per_block``-row query tile.
-    The fused pool means ONE ``(bs, 2*KV, hd)`` page per DMA instead of a
-    (k, v) pair — the ring holds half as many transfers in flight for the
-    same bytes.  The ring is double-buffered over page GROUPS: group ``t+1``
-    starts before group ``t`` is waited, so a whole group's pages stream
-    behind the flash inner loop.  Pad entries fetch a real page and skip
-    only the compute, keeping every started copy waited exactly once.
+    The fused pool means ONE ``(KV, bs, 2*hd)`` page per DMA instead of a
+    (k, v) pair; its minor axis holds K and V side by side, so a
+    head_dim-64 page moves whole 128-lane rows with the page size on the
+    sublanes — the tiling the DMA engine needs.
+    The ring is double-buffered over page GROUPS: group ``t+1`` starts
+    before group ``t`` is waited, so a whole group's pages stream behind
+    the flash inner loop.  Pad entries fetch a real page and skip only the
+    compute, keeping every started copy waited exactly once.
 
-    The per-page math is byte-for-byte ``_chunked_flash_update`` +
-    ``_chunked_valid_mask`` on split VIEWS of the fused tile — the ragged
-    and chunked paths cannot drift.
+    The per-page math is ``_flash_update`` + ``_chunked_valid_mask`` on the
+    K and V lane halves of the fused page — the ragged and chunked paths
+    cannot drift.
     """
     t = pl.program_id(1)
     Tg = pl.num_programs(1)
+    hd = head_dim
 
     def start_group(g):
         slot = jax.lax.rem(g, 2)
@@ -436,11 +453,7 @@ def _ragged_kernel(
 
     @pl.when(t == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        # Lanes with no valid keys (padding, empty requests) must read 0.
-        o_ref[...] = jnp.zeros_like(o_ref)
+        _init_tile(acc_ref, m_ref, l_ref, o_ref)
         start_group(jnp.int32(0))                 # warm-up: fill slot 0
 
     @pl.when(t + 1 < Tg)                          # steady state: run ahead
@@ -449,8 +462,7 @@ def _ragged_kernel(
 
     slot = jax.lax.rem(t, 2)
     for j in range(pages):                        # static small loop
-        g = t
-        blk = jnp.minimum(block_list[g * pages + j], num_blocks - 1)
+        blk = jnp.minimum(block_list[t * pages + j], num_blocks - 1)
         pltpu.make_async_copy(kv_hbm.at[blk], kv_buf.at[slot, j],
                               kv_sem.at[slot, j]).wait()
         e = t * pages + j
@@ -461,11 +473,11 @@ def _ragged_kernel(
             valid = _chunked_valid_mask(block_req, block_pos, kv_lens,
                                         treq_ref, tpos_ref, e, bs=bs,
                                         num_reqs=num_reqs)
-            tile = kv_buf[slot, j]                # (bs, 2*KV, hd) fused page
-            split = tile.reshape(bs, num_kv, 2, tile.shape[-1])
-            _chunked_flash_update(q_ref, split[:, :, 0, :], split[:, :, 1, :],
-                                  o_ref, acc_ref, m_ref, l_ref, valid,
-                                  num_kv=num_kv, sm_scale=sm_scale)
+            _flash_update(
+                q_ref, lambda kv: (kv_buf[slot, j, kv, :, :hd],
+                                   kv_buf[slot, j, kv, :, hd:]),
+                o_ref, acc_ref, m_ref, l_ref, valid, num_kv=num_kv,
+                sm_scale=sm_scale)
 
 
 def paged_attention_ragged_pallas(q, kv_pool, block_list, block_req,
@@ -479,11 +491,12 @@ def paged_attention_ragged_pallas(q, kv_pool, block_list, block_req,
 
     Same contract as ``repro.core.attention_api.paged_attention_ragged``:
     q (T, H, hd) flat token lanes with sequences contiguous in lane order,
-    kv_pool (NB, BS, 2*KV, hd) fused head-interleaved layer, flat BlockList
-    arrays (Tb,), and cu_q_lens/cu_kv_lens/seq_slot ragged metadata.  The
-    lane arrays the grid masks against are DERIVED from the prefix sums at
-    the XLA level (``ragged_lane_metadata`` — the same integer math as the
-    jnp ref), then scalar-prefetched exactly like the chunked kernel.
+    kv_pool (NB, KV, BS, 2*hd) fused layer (K and V side by side on the
+    minor axis), flat BlockList arrays (Tb,), and cu_q_lens/cu_kv_lens/
+    seq_slot ragged metadata.  The lane arrays the grid masks against are
+    DERIVED from the prefix sums at the XLA level (``ragged_lane_metadata``
+    — the same integer math as the jnp ref), then scalar-prefetched exactly
+    like the chunked kernel.
 
     Tunables (registered on the ``paged_attention_ragged`` family, measured
     by the autotune sweep in ``benchmarks/paged_attention_bench.py``):
@@ -493,14 +506,14 @@ def paged_attention_ragged_pallas(q, kv_pool, block_list, block_req,
     * ``num_kv_pages_per_block`` — fused KV pages one grid step consumes;
       the double-buffered DMA ring holds ``2 *`` this many pages in VMEM.
     * ``vmem_limit_bytes`` — cap on the ring's VMEM footprint: the page
-      group is clamped so the ring fits, and the limit is forwarded to the
-      Mosaic compiler when this jax version accepts it.
+      group is clamped so the ring fits, and the limit is passed to the
+      Mosaic compiler.
     """
     from repro.core.attention_api import ragged_lane_metadata
 
     T, H, hd = q.shape
-    NB, BS, KV2, _ = kv_pool.shape
-    num_kv = KV2 // 2
+    NB, num_kv, BS, hd2 = kv_pool.shape
+    assert hd2 == 2 * hd, (kv_pool.shape, q.shape)
     B = seq_slot.shape[0]
     Tb = block_list.shape[0]
     scale = float(sm_scale if sm_scale is not None else hd ** -0.5)
@@ -510,19 +523,12 @@ def paged_attention_ragged_pallas(q, kv_pool, block_list, block_req,
 
     pages = max(int(num_kv_pages_per_block), 1)
     if vmem_limit_bytes:
-        page_bytes = BS * KV2 * hd * jnp.dtype(kv_pool.dtype).itemsize
+        page_bytes = BS * num_kv * hd2 * jnp.dtype(kv_pool.dtype).itemsize
         pages = max(min(pages, int(vmem_limit_bytes) // (2 * page_bytes)), 1)
     tq = max(min(int(num_queries_per_block), T), 1)
-
-    pad = (-T) % tq
-    if pad:
-        q = jnp.pad(q, ((0, pad), (0, 0), (0, 0)))
-        # Padding lanes get an out-of-range owner so every key is masked.
-        token_req = jnp.pad(token_req, (0, pad), constant_values=B)
-        token_pos = jnp.pad(token_pos, (0, pad))
-    Tp = T + pad
-    treq = token_req.reshape(Tp, 1).astype(jnp.int32)
-    tpos = token_pos.reshape(Tp, 1).astype(jnp.int32)
+    q, token_req, token_pos = _pad_lanes(q, token_req, token_pos, tq, B)
+    qg, treq, tpos = _group_lanes(q, token_req, token_pos, num_kv, tq)
+    n, _, R, _ = qg.shape
 
     bpad = (-Tb) % pages
     if bpad:
@@ -533,44 +539,41 @@ def paged_attention_ragged_pallas(q, kv_pool, block_list, block_req,
     Tg = (Tb + bpad) // pages
 
     kernel = functools.partial(
-        _ragged_kernel, bs=BS, num_kv=num_kv, num_reqs=B, sm_scale=scale,
-        pages=pages, num_blocks=NB)
+        _ragged_kernel, bs=BS, num_kv=num_kv, head_dim=hd, num_reqs=B,
+        sm_scale=scale, pages=pages, num_blocks=NB)
 
     # index maps take (grid ids, *prefetched scalars)
     def q_map(i, t, bl, br, bp, kvl):
-        return (i, 0, 0)
+        return (i, 0, 0, 0)
 
     def lane_map(i, t, bl, br, bp, kvl):
-        return (i, 0)
+        return (i, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(Tp // tq, Tg),
+        grid=(n, Tg),
         in_specs=[
-            pl.BlockSpec((tq, H, hd), q_map),
+            pl.BlockSpec((1, num_kv, R, hd), q_map),
             # ONE buffer in HBM; the kernel rings its own fused-page DMAs.
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec((tq, 1), lane_map),
-            pl.BlockSpec((tq, 1), lane_map),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((1, R, 1), lane_map),
+            pl.BlockSpec((1, R, 1), lane_map),
         ],
-        out_specs=pl.BlockSpec((tq, H, hd), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((tq, H, hd), jnp.float32),
-            pltpu.VMEM((tq, H), jnp.float32),
-            pltpu.VMEM((tq, H), jnp.float32),
-            pltpu.VMEM((2, pages, BS, KV2, hd), kv_pool.dtype),
+        out_specs=pl.BlockSpec((1, num_kv, R, hd), q_map),
+        scratch_shapes=_lane_scratch(num_kv, R, hd) + [
+            pltpu.VMEM((2, pages, num_kv, BS, hd2), kv_pool.dtype),
             pltpu.SemaphoreType.DMA((2, pages)),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Tp, H, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
         # The ring state spans grid steps of the q-tile dim too (warm-up
         # reruns per tile), so neither dimension may be parallelized.
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=int(vmem_limit_bytes) or None),
         interpret=interpret,
-    )(block_list, block_req, block_pos, kv_lens, q, kv_pool, treq, tpos)
-    return out[:T]
+    )(block_list, block_req, block_pos, kv_lens, qg, kv_pool, treq, tpos)
+    return _ungroup_lanes(out, T, tq)

@@ -40,7 +40,7 @@ from repro.core.attention_api import (
     paged_attention_chunked as _chunked_jnp,
     paged_attention_chunked_sharded, paged_attention_opt,
     paged_attention_ragged as _ragged_jnp, paged_attention_ragged_sharded)
-from repro.kernels.compat import shard_map as _shard_map
+from repro.distributed.sharding import auto_mesh
 from repro.kernels.paged_attention.kernel import (
     paged_attention_chunked_pallas, paged_attention_pallas,
     paged_attention_ragged_pallas)
@@ -197,13 +197,13 @@ def _sharded_chunked_fn(ndev: int):
     registry rule: impls are registered pre-jitted; a fresh closure per
     call would retrace every time).
     """
-    mesh = jax.make_mesh((ndev,), ("seq",))
-    fn = _shard_map(
+    mesh = auto_mesh((ndev,), ("seq",))
+    fn = jax.shard_map(
         partial(paged_attention_chunked_sharded, axis="seq"),
         mesh=mesh,
         in_specs=(P(), P(), P(), P("seq"), P("seq"), P("seq"), P(), P(),
                   P()),
-        out_specs=P(), check_rep=False)
+        out_specs=P(), check_vma=False)
     return jax.jit(fn)
 
 
@@ -282,12 +282,12 @@ def _sharded_ragged_fn(ndev: int):
     """Jitted shard_map ragged combine — the chunked combine's twin over the
     fused pool, with the cu prefix sums replicated (every rank derives the
     same lane metadata; only the BlockList splits)."""
-    mesh = jax.make_mesh((ndev,), ("seq",))
-    fn = _shard_map(
+    mesh = auto_mesh((ndev,), ("seq",))
+    fn = jax.shard_map(
         partial(paged_attention_ragged_sharded, axis="seq"),
         mesh=mesh,
         in_specs=(P(), P(), P("seq"), P("seq"), P("seq"), P(), P(), P()),
-        out_specs=P(), check_rep=False)
+        out_specs=P(), check_vma=False)
     return jax.jit(fn)
 
 

@@ -14,7 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
 
 LANES = 128
 
@@ -46,7 +45,7 @@ def _call(kernel, args, rows, block_rows, dtype, n_scalar=0,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, LANES), dtype),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*args)

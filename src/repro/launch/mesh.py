@@ -3,17 +3,21 @@
 ``make_production_mesh`` is a FUNCTION (not a module constant) so importing
 this module never touches jax device state — required because the dry-run
 sets ``xla_force_host_platform_device_count`` before first jax init.
+
+Every mesh here has ``Auto`` axes (``repro.distributed.sharding.auto_mesh``).
 """
 from __future__ import annotations
 
 import jax
+
+from repro.distributed.sharding import auto_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 single pod (256 chips) or 2×16×16 (512 chips, 2 pods)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_smoke_mesh(data: int = 1, model: int = 1):
@@ -21,7 +25,7 @@ def make_smoke_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = min(model, n // data)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return auto_mesh((data, model), ("data", "model"))
 
 
 def make_serving_mesh(model: int = 0):
@@ -42,7 +46,7 @@ def make_serving_mesh(model: int = 0):
             f"only {n} local device(s) exist (set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={model} on CPU hosts)")
     model = n if model <= 0 else model
-    return jax.make_mesh((1, model), ("data", "model"))
+    return auto_mesh((1, model), ("data", "model"))
 
 
 def data_axes(mesh) -> tuple:

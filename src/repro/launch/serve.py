@@ -16,6 +16,7 @@ import jax
 import numpy as np
 
 from repro.config import ServeConfig, get_config
+from repro.launch import runtime
 from repro.models.api import build_model
 from repro.serving.engine import Request, ServingEngine
 
@@ -126,6 +127,7 @@ def main() -> None:
             p.error("--policy takes one name or admission/preemption/eviction")
         args.admission, args.preemption, args.eviction = parts
 
+    runtime.enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced(dtype="float32")
@@ -210,6 +212,7 @@ def main() -> None:
               f"tpot={report.attainment_tpot:.0%}  "
               f"SLO {'MET' if report.ok else 'MISSED'} "
               f"(targets {args.slo_ttft}s / {args.slo_tpot}s)")
+    print(f"served on {runtime.device_line()}")
     print(f"served {m['finished']} requests, {m['output_tokens']} tokens "
           f"in {dt:.2f}s ({m['output_tokens']/dt:.1f} tok/s) "
           f"[backend={m['backend']} devices={m['devices']} "

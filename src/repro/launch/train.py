@@ -15,6 +15,7 @@ from repro.checkpoint import CheckpointManager
 from repro.config import get_config
 from repro.data.pipeline import DataPipeline, SyntheticLMDataset
 from repro.distributed.sharding import ShardingRules
+from repro.launch import runtime
 from repro.launch.mesh import make_smoke_mesh
 from repro.models.api import build_model
 from repro.optim import adamw, cosine_warmup
@@ -36,6 +37,7 @@ def main() -> None:
     p.add_argument("--dtype", default="float32")
     args = p.parse_args()
 
+    runtime.enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced(dtype=args.dtype)

@@ -279,13 +279,13 @@ class TransformerLM:
                                lists, attn_impl="ragged"):
         """One layer's pool append + attention under shard_map (mesh path).
 
-        ``pkv`` is the FUSED head-interleaved pool layer, sequence-sharded
+        ``pkv`` is the FUSED pool layer, sequence-sharded
         on its block dimension over ``axis``;
         ``block_list``/``block_req``/``block_pos`` are the (S, M) per-shard
         LOCAL BlockLists from ``BlockAllocator.build_sharded_block_lists``.
         Each rank translates the global write slots to local indices
         (non-owned lanes get an out-of-bounds sentinel the scatter drops),
-        appends its lanes' interleaved KV to its pool shard in ONE scatter,
+        appends its lanes' fused KV to its pool shard in ONE scatter,
         computes flash partials against its local list, and the log-sum-exp
         combine (``paged_attention_ragged_sharded`` /
         ``paged_attention_chunked_sharded`` per ``attn_impl``; the ragged
@@ -293,8 +293,6 @@ class TransformerLM:
         across ``axis`` — the KV never leaves its shard.
         """
         from jax.sharding import PartitionSpec as P
-
-        from repro.kernels.compat import shard_map
 
         ragged = attn_impl == "ragged"
 
@@ -306,8 +304,7 @@ class TransformerLM:
             # Non-owned lanes -> index == per: out of local bounds, dropped.
             local_blk = jnp.where(blk // per == s, blk - s * per, per)
             lslots = jnp.stack([local_blk, slots[:, 1]], axis=-1)
-            pkv = paged_kv.append_to_pool(
-                pkv, paged_kv.fuse_kv_heads(k_new, v_new), lslots)
+            pkv = paged_kv.append_to_fused_pool(pkv, k_new, v_new, lslots)
             if ragged:
                 ctx = attention_api.paged_attention_ragged_sharded(
                     q, pkv, bl[0], br[0], bp[0], cu_q, cu_kv, seq_slot,
@@ -319,11 +316,11 @@ class TransformerLM:
                     token_pos, axis=axis)
             return pkv, ctx
 
-        fn = shard_map(
+        fn = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(), P(), P(), P(axis), P(axis), P(axis), P(axis),
                       P(), P(), P(), P(), P(), P(), P()),
-            out_specs=(P(axis), P()), check_rep=False)
+            out_specs=(P(axis), P()), check_vma=False)
         return fn(q, k_new, v_new, pkv, lists["block_list"],
                   lists["block_req"], lists["block_pos"], lists["kv_lens"],
                   lists["token_req"], lists["token_pos"],
@@ -345,7 +342,7 @@ class TransformerLM:
         (T,) is one token of some request — a decode token (one lane per
         decoding request) or one token of a prompt chunk (several lanes per
         prefilling request). Per layer the lane KV is appended to the FUSED
-        head-interleaved pool (``pools["kv"]``, one scatter per layer), then
+        pool (``pools["kv"]``, one scatter per layer), then
         every lane attends causally to its request's blocks through the op
         family ``attn_impl`` picks: ``"ragged"`` =
         :func:`attention_api.paged_attention_ragged_op` consuming the cu
@@ -408,9 +405,8 @@ class TransformerLM:
             else:
                 # Padding lanes carry out-of-bounds slots -> scatter drops
                 # them.
-                pkv = paged_kv.append_to_pool(
-                    pkv, paged_kv.fuse_kv_heads(k_new[:, 0], v_new[:, 0]),
-                    lists["slots"])
+                pkv = paged_kv.append_to_fused_pool(
+                    pkv, k_new[:, 0], v_new[:, 0], lists["slots"])
                 if ragged:
                     ctx = attention_api.paged_attention_ragged_op(
                         q[:, 0], pkv, lists["block_list"],

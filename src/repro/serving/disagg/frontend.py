@@ -94,11 +94,11 @@ def copy_block_tokens(dst_pools, src_pools, src_slots: np.ndarray,
     sb, so = np.asarray(src_slots[:, 0]), np.asarray(src_slots[:, 1])
     db, do = jnp.asarray(dst_slots[:, 0]), jnp.asarray(dst_slots[:, 1])
     out = dict(dst_pools)
-    for c in dst_pools:          # ONE fused kv channel with the fused pool
+    for c in dst_pools:          # ONE fused (L, NB, KV, BS, 2*HD) channel
         # documented host roundtrip — declared to the host-sync sanitizer
-        vals = sanitize_lib.host_read(src_pools[c][:, sb, so],
-                                      reason="disagg-handoff")  # (L, n, ...)
-        out[c] = dst_pools[c].at[:, db, do].set(
+        vals = sanitize_lib.host_read(src_pools[c][:, sb, :, so],
+                                      reason="disagg-handoff")  # (n, L, ...)
+        out[c] = dst_pools[c].at[:, db, :, do].set(
             jnp.asarray(vals, dst_pools[c].dtype))
     return out
 

@@ -195,10 +195,10 @@ class ServingEngine:
                     "promote block copies assume single-device block slices)")
             self.host_pool = HostPool(serve.host_blocks)
             self.alloc.host_pool = self.host_pool
-        # ONE fused head-interleaved buffer ([K0, V0, K1, V1, ...] on the
-        # head axis): the allocator, CoW drain, tier demote/promote and the
-        # disagg handoff each move a single pool; the chunked path reads it
-        # through split views (repro.core.paged_kv.fused_kv_views).
+        # ONE fused buffer (K and V side by side on the minor axis): the
+        # allocator, CoW drain, tier demote/promote and the disagg handoff
+        # each move a single pool; the chunked path reads it through split
+        # views (repro.core.paged_kv.fused_kv_views).
         self.pools = {"kv": make_fused_pool(
             cfg.num_layers, nb, bs, a.num_kv_heads, a.head_dim,
             jnp.dtype(cfg.dtype))}

@@ -100,14 +100,28 @@ def test_precedence_scope_over_env_over_config(monkeypatch):
         assert fam.resolve("xla").backend == "xla"
 
 
-def test_unsupported_preference_falls_back_to_auto():
+def test_unsupported_preference_falls_back_to_auto(monkeypatch):
+    """A preference naming a registered backend that rejects the call
+    raises like an explicit request — no silent re-deciding at any level.
+    Only a name the family does not register falls through to auto."""
     if jax.default_backend() == "tpu":
         pytest.skip("CPU-only check")
     fam = dispatch.get_op("paged_attention")
     with dispatch.force_backend("pallas"):
-        assert fam.resolve().backend == "xla"      # graceful degrade
+        with pytest.raises(dispatch.BackendUnavailableError):
+            fam.resolve()                           # scope
     with pytest.raises(dispatch.BackendUnavailableError):
-        fam.resolve("pallas")                       # explicit stays strict
+        fam.resolve(config="pallas")                # config hint
+    with pytest.raises(dispatch.BackendUnavailableError):
+        fam.resolve("pallas")                       # explicit
+    monkeypatch.setenv(dispatch.ENV_VAR, "pallas")
+    with pytest.raises(dispatch.BackendUnavailableError):
+        fam.resolve()                               # env
+    monkeypatch.delenv(dispatch.ENV_VAR)
+    # an unregistered name in this family is no claim about the device
+    assert "sharded" not in fam.backends()
+    with dispatch.force_backend("sharded"):
+        assert fam.resolve().backend == "xla"
 
 
 def test_shape_capability_fallback():

@@ -101,10 +101,9 @@ def test_compressed_psum_with_error_feedback():
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
     from repro.distributed.compression import compressed_psum
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:  # pre-0.5 jax
-        from jax.experimental.shard_map import shard_map
-    mesh = jax.make_mesh((8,), ("x",))
+    from jax import shard_map
+    from repro.distributed.sharding import auto_mesh
+    mesh = auto_mesh((8,), ("x",))
     g = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
 
     def f(g, r):
@@ -133,9 +132,7 @@ def test_pipeline_matches_reference():
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
     from repro.distributed.pipeline import bubble_fraction, pipeline_forward
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:  # pre-0.5 jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     S, M, mb, D = 4, 6, 2, 8
     mesh = jax.make_mesh((S,), ("pp",))
     ws = jax.random.normal(jax.random.PRNGKey(0), (S, D, D)) * 0.3

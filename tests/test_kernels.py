@@ -135,7 +135,7 @@ def test_paged_attention_chunked_sharded_equals_chunked():
         paged_attention_chunked, paged_attention_chunked_sharded)
     from repro.core.dispatch import get_op
     from repro.core.paged_kv import BlockAllocator
-    from repro.kernels.compat import shard_map
+    from repro.distributed.sharding import auto_mesh
 
     SHARDS, BS, KV, hd, H = 4, 8, 2, 32, 8
     NB = SHARDS * 6
@@ -167,15 +167,15 @@ def test_paged_attention_chunked_sharded_equals_chunked():
     # engine form: sequence-sharded pool + per-shard LOCAL lists
     sbl, sbr, sbp = al.build_sharded_block_lists(
         [(r, r) for r in range(B)], pad_req=B)
-    mesh = jax.make_mesh((SHARDS,), ("model",))
-    fn = shard_map(
+    mesh = auto_mesh((SHARDS,), ("model",))
+    fn = jax.shard_map(
         lambda q, pk, pv, bl, br, bp: paged_attention_chunked_sharded(
             q, pk, pv, bl[0], br[0], bp[0], kv_lens, treq, tpos,
             axis="model"),
         mesh=mesh,
         in_specs=(P(), P("model"), P("model"), P("model"), P("model"),
                   P("model")),
-        out_specs=P(), check_rep=False)
+        out_specs=P(), check_vma=False)
     out = jax.jit(fn)(q, pk, pv, jnp.asarray(sbl), jnp.asarray(sbr),
                       jnp.asarray(sbp))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),

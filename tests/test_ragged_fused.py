@@ -87,7 +87,7 @@ def test_fused_pool_roundtrip_and_block_copy():
     k = jax.random.normal(ks[0], (3, NB, BS, KV, HD))
     v = jax.random.normal(ks[1], (3, NB, BS, KV, HD))
     fused = fuse_kv_heads(k, v)
-    assert fused.shape == (3, NB, BS, 2 * KV, HD)
+    assert fused.shape == (3, NB, KV, BS, 2 * HD)
     k2, v2 = fused_kv_views(fused)
     assert np.array_equal(np.asarray(k2), np.asarray(k))
     assert np.array_equal(np.asarray(v2), np.asarray(v))
@@ -138,13 +138,14 @@ def test_engine_fused_pool_and_metrics(serving_ref):
         assert key in m, key
         assert m["policy_counters"]["tune.tuned_resolved"] + \
             m["policy_counters"]["tune.tuned_fallback"] == 1
-    # ONE fused channel, head-interleaved: (L, NB, BS, 2*KV, HD)
+    # ONE fused channel, head-major, K|V on the minor axis: (L, NB, KV, BS,
+    # 2*HD)
     eng_serve = ServeConfig(model=cfg.name, kv_block_size=4, max_batch=2)
     eng = ServingEngine(model, params, cfg, eng_serve, num_blocks=8)
     assert set(eng.pools) == {"kv"}
     a = cfg.attention
     assert eng.pools["kv"].shape == (
-        cfg.num_layers, 8, 4, 2 * a.num_kv_heads, a.head_dim)
+        cfg.num_layers, 8, a.num_kv_heads, 4, 2 * a.head_dim)
 
 
 def test_engine_ragged_vs_chunked_greedy_parity(serving_ref):

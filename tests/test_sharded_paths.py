@@ -16,9 +16,7 @@ def test_paged_attention_sharded_equals_opt():
     from repro.core.attention_api import (
         paged_attention_opt, paged_attention_sharded)
     from repro.core.paged_kv import BlockAllocator
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:  # pre-0.5 jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     SHARDS, BS, KV, HD, H, B = 4, 4, 2, 16, 4, 3
     NB_PER = 8
@@ -84,9 +82,7 @@ def test_row_sharded_embedding_equals_dense():
     from jax.sharding import PartitionSpec as P
     from repro.core.embedding_api import (
         batched_table_lookup, batched_table_lookup_sharded)
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:  # pre-0.5 jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     SHARDS, T, R, D, B, L = 4, 3, 16, 8, 2, 5
     big = jax.random.normal(jax.random.PRNGKey(0), (T * R, D))
     offs = jnp.arange(T, dtype=jnp.int32) * R
