@@ -1,0 +1,9 @@
+"""ttft_p95_ms: 95th percentile (nearest rank) of time to first token over
+every request sent in the window, from when it was due."""
+from bench import stats
+from bench.metrics._latency import ttfts
+
+
+def read(run):
+    v = stats.percentile(ttfts(run), 95)
+    return None if v is None else v * 1e3
