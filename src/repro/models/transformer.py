@@ -304,16 +304,19 @@ class TransformerLM:
             # Non-owned lanes -> index == per: out of local bounds, dropped.
             local_blk = jnp.where(blk // per == s, blk - s * per, per)
             lslots = jnp.stack([local_blk, slots[:, 1]], axis=-1)
-            pkv = paged_kv.append_to_fused_pool(pkv, k_new, v_new, lslots)
-            if ragged:
-                ctx = attention_api.paged_attention_ragged_sharded(
-                    q, pkv, bl[0], br[0], bp[0], cu_q, cu_kv, seq_slot,
-                    axis=axis)
-            else:
-                pk, pv = paged_kv.fused_kv_views(pkv)
-                ctx = attention_api.paged_attention_chunked_sharded(
-                    q, pk, pv, bl[0], br[0], bp[0], kv_lens, token_req,
-                    token_pos, axis=axis)
+            with jax.named_scope("kv_append"):
+                pkv = paged_kv.append_to_fused_pool(pkv, k_new, v_new,
+                                                    lslots)
+            with jax.named_scope("attention"):
+                if ragged:
+                    ctx = attention_api.paged_attention_ragged_sharded(
+                        q, pkv, bl[0], br[0], bp[0], cu_q, cu_kv, seq_slot,
+                        axis=axis)
+                else:
+                    pk, pv = paged_kv.fused_kv_views(pkv)
+                    ctx = attention_api.paged_attention_chunked_sharded(
+                        q, pk, pv, bl[0], br[0], bp[0], kv_lens, token_req,
+                        token_pos, axis=axis)
             return pkv, ctx
 
         fn = jax.shard_map(
@@ -391,13 +394,15 @@ class TransformerLM:
             raise ValueError(
                 f"attn_impl {attn_impl!r}: expected 'ragged' or 'chunked'")
         ragged = attn_impl == "ragged"
-        x = embed(params["embed"], tokens)                 # (T, D)
+        with jax.named_scope("embed"):
+            x = embed(params["embed"], tokens)             # (T, D)
 
         def body(x, inp):
             lp, pkv = inp
-            h = rmsnorm(lp["ln1"], x[:, None], cfg.norm_eps)
-            q, k_new, v_new = attn_lib.project_qkv(lp["attn"], h, a,
-                                                   token_pos[:, None])
+            with jax.named_scope("qkv"):
+                h = rmsnorm(lp["ln1"], x[:, None], cfg.norm_eps)
+                q, k_new, v_new = attn_lib.project_qkv(lp["attn"], h, a,
+                                                       token_pos[:, None])
             if mesh is not None:
                 pkv, ctx = self._sharded_append_attend(
                     mesh, axis or "model", q[:, 0], k_new[:, 0],
@@ -405,49 +410,55 @@ class TransformerLM:
             else:
                 # Padding lanes carry out-of-bounds slots -> scatter drops
                 # them.
-                pkv = paged_kv.append_to_fused_pool(
-                    pkv, k_new[:, 0], v_new[:, 0], lists["slots"])
-                if ragged:
-                    ctx = attention_api.paged_attention_ragged_op(
-                        q[:, 0], pkv, lists["block_list"],
-                        lists["block_req"], lists["block_pos"],
-                        lists["cu_q_lens"], lists["cu_kv_lens"],
-                        lists["seq_slot"], backend=attn_backend,
-                        num_queries_per_block=num_queries_per_block,
-                        num_kv_pages_per_block=num_kv_pages_per_block,
-                        vmem_limit_bytes=vmem_limit_bytes)
+                with jax.named_scope("kv_append"):
+                    pkv = paged_kv.append_to_fused_pool(
+                        pkv, k_new[:, 0], v_new[:, 0], lists["slots"])
+                with jax.named_scope("attention"):
+                    if ragged:
+                        ctx = attention_api.paged_attention_ragged_op(
+                            q[:, 0], pkv, lists["block_list"],
+                            lists["block_req"], lists["block_pos"],
+                            lists["cu_q_lens"], lists["cu_kv_lens"],
+                            lists["seq_slot"], backend=attn_backend,
+                            num_queries_per_block=num_queries_per_block,
+                            num_kv_pages_per_block=num_kv_pages_per_block,
+                            vmem_limit_bytes=vmem_limit_bytes)
+                    else:
+                        pk, pv = paged_kv.fused_kv_views(pkv)
+                        ctx = attention_api.paged_attention_chunked_op(
+                            q[:, 0], pk, pv, lists["block_list"],
+                            lists["block_req"], lists["block_pos"],
+                            lists["kv_lens"], lists["token_req"], token_pos,
+                            backend=attn_backend, q_chunk=q_chunk,
+                            prefetch_depth=prefetch_depth)
+            with jax.named_scope("attn_out"):
+                x = x + jnp.einsum("be,ed->bd", ctx.reshape(x.shape[0], -1),
+                                   lp["attn"]["wo"])
+            with jax.named_scope("mlp"):
+                h = rmsnorm(lp["ln2"], x[:, None], cfg.norm_eps)
+                if cfg.moe is not None:
+                    o, _ = moe_lib.moe_apply(lp["moe"], h, cfg.moe,
+                                             shard=self.shard_moe,
+                                             full_capacity=True,
+                                             groups=self.moe_groups)
                 else:
-                    pk, pv = paged_kv.fused_kv_views(pkv)
-                    ctx = attention_api.paged_attention_chunked_op(
-                        q[:, 0], pk, pv, lists["block_list"],
-                        lists["block_req"], lists["block_pos"],
-                        lists["kv_lens"], lists["token_req"], token_pos,
-                        backend=attn_backend, q_chunk=q_chunk,
-                        prefetch_depth=prefetch_depth)
-            x = x + jnp.einsum("be,ed->bd", ctx.reshape(x.shape[0], -1),
-                               lp["attn"]["wo"])
-            h = rmsnorm(lp["ln2"], x[:, None], cfg.norm_eps)
-            if cfg.moe is not None:
-                o, _ = moe_lib.moe_apply(lp["moe"], h, cfg.moe,
-                                         shard=self.shard_moe,
-                                         full_capacity=True,
-                                         groups=self.moe_groups)
-            else:
-                o = mlp_apply(lp["mlp"], h, cfg.act)
-            return x + o[:, 0], pkv
+                    o = mlp_apply(lp["mlp"], h, cfg.act)
+                x = x + o[:, 0]
+            return x, pkv
 
         x, pkv = jax.lax.scan(body, x, (params["layers"], pools["kv"]))
-        if "logit_lanes" in lists:
-            # Speculative verify: a row per (slot, lane) pair, (B, R, V).
-            x_sel = jnp.take(x, lists["logit_lanes"], axis=0)   # (B, R, D)
-            x_sel = rmsnorm(params["final_norm"], x_sel, cfg.norm_eps)
-            return (unembed(params.get("head", params["embed"]), x_sel),
-                    {"kv": pkv})
-        # Unembed only each slot's last valid lane: (B, D) -> (B, V).
-        x_last = jnp.take(x, lists["last_lane"], axis=0)
-        x_last = rmsnorm(params["final_norm"], x_last[:, None], cfg.norm_eps)
-        logits = unembed(params.get("head", params["embed"]), x_last)[:, 0]
-        return logits, {"kv": pkv}
+        head = params.get("head", params["embed"])
+        with jax.named_scope("unembed"):
+            if "logit_lanes" in lists:
+                # Speculative verify: a row per (slot, lane) pair, (B, R, V).
+                x_sel = jnp.take(x, lists["logit_lanes"], axis=0)  # (B, R, D)
+                x_sel = rmsnorm(params["final_norm"], x_sel, cfg.norm_eps)
+                return unembed(head, x_sel), {"kv": pkv}
+            # Unembed only each slot's last valid lane: (B, D) -> (B, V).
+            x_last = jnp.take(x, lists["last_lane"], axis=0)
+            x_last = rmsnorm(params["final_norm"], x_last[:, None],
+                             cfg.norm_eps)
+            return unembed(head, x_last)[:, 0], {"kv": pkv}
 
     # ---------------------------------------------------------------- loss
     def loss(self, params, batch):

@@ -75,11 +75,19 @@ from repro.serving import request as request_lib
 from repro.serving.metrics import EngineMetrics
 from repro.serving.request import Request, RequestState, SamplingParams
 from repro.serving.scheduler import Scheduler, StepPlan
+from repro.serving.spans import Spans
 
 __all__ = ["Request", "RequestState", "SamplingParams", "ServingEngine"]
 
 
 _bucket = request_lib.bucket_pow2      # lane/slot counts -> power-of-two
+
+
+def _lanes(plan: StepPlan) -> Dict[str, int]:
+    """A plan's lanes by kind: prompt-chunk tokens and decoding requests
+    (a request's drafted lanes count in neither)."""
+    return {"prefill_tokens": sum(n for _, n in plan.prefill),
+            "decode_tokens": len(plan.decode)}
 
 
 class _PendingStep:
@@ -96,10 +104,10 @@ class _PendingStep:
     """
 
     __slots__ = ("actions", "slots", "chain", "nxt_dev", "cancelled",
-                 "phases", "num_tokens", "t_dispatch")
+                 "phases", "num_tokens", "lanes", "t_dispatch")
 
     def __init__(self, *, actions, slots, chain, nxt_dev, phases,
-                 num_tokens, t_dispatch):
+                 num_tokens, lanes, t_dispatch):
         # actions: (kind, req, n, pos0, out_idx) — kind "decode"/"prefill";
         # out_idx indexes the placeholder in req.output (None: chunk-only
         # prefill, nothing to resolve).  slots: req_id -> slot snapshot at
@@ -111,6 +119,7 @@ class _PendingStep:
         self.cancelled: set = set()
         self.phases = phases
         self.num_tokens = num_tokens
+        self.lanes = lanes
         self.t_dispatch = t_dispatch
 
     def cancel(self, req) -> None:
@@ -247,6 +256,7 @@ class ServingEngine:
             self.attn_backend = dispatch.resolve(
                 fam, config=serve.backend).backend
         self._metrics = EngineMetrics(backend=self.attn_backend)
+        self._spans = Spans()
         self._key = jax.random.PRNGKey(seed)
         self._step_count = 0
         # Async overlapped loop (docs/async_engine.md): with overlap on,
@@ -313,8 +323,9 @@ class ServingEngine:
                 params, pools, lists, tokens, attn_backend=attn_backend,
                 q_chunk=q_chunk, prefetch_depth=prefetch_depth, mesh=mesh,
                 axis=mesh_axis, attn_impl=attn_impl, **attn_tunables)
-            nxt = sampling_lib.sample_batched(key, logits, temps, top_ks,
-                                              top_ps)
+            with jax.named_scope("sample"):
+                nxt = sampling_lib.sample_batched(key, logits, temps,
+                                                  top_ks, top_ps)
             return nxt, pools
 
         self._step_fn = jax.jit(fused)
@@ -354,8 +365,10 @@ class ServingEngine:
                     q_chunk=q_chunk, prefetch_depth=prefetch_depth,
                     mesh=mesh, axis=mesh_axis, attn_impl=attn_impl,
                     **attn_tunables)
-                out, acc = spec_lib.verify_batched(
-                    key, logits, drafts, draft_lens, temps, top_ks, top_ps)
+                with jax.named_scope("verify"):
+                    out, acc = spec_lib.verify_batched(
+                        key, logits, drafts, draft_lens, temps, top_ks,
+                        top_ps)
                 return out, acc, pools
 
             self._spec_step_fn = jax.jit(fused_spec)
@@ -575,8 +588,18 @@ class ServingEngine:
         Overlap off dispatches and resolves in the same call — identical
         behaviour to the serial loop. Greedy output streams are
         bit-identical either way (docs/async_engine.md).
+
+        Each part runs inside a host span (``repro.serving.spans``):
+        ``engine.step`` holds ``propose``, ``schedule``, ``render`` (inputs
+        and the step key), ``drain`` (pool traffic), ``dispatch`` (the
+        jitted call), ``wait`` (blocking on the tokens) and ``commit``;
+        ``phase_s`` is rolled up from their readings.
         """
-        t0 = time.perf_counter()
+        with self._spans.span("step"):
+            return self._step()
+
+    def _step(self) -> int:
+        span = self._spans.span
         if self.proposer is not None and self._pending is not None:
             # Proposers read the tail of req.output; under overlap its last
             # entry may still be an unresolved placeholder, which would
@@ -588,21 +611,27 @@ class ServingEngine:
             if not self.scheduler.has_work():
                 return 0        # the resolve finished the last requests —
                                 # this iteration was a drain, not an idle tick
-        drafts = self._propose() if self.proposer is not None else {}
-        t1 = time.perf_counter()
-        plan = self.scheduler.schedule(spec_drafts=drafts)
+        drafts: Dict[int, np.ndarray] = {}
+        propose_s = 0.0
+        if self.proposer is not None:
+            with span("propose") as sp:
+                drafts = self._propose()
+            propose_s = sp.s
+        with span("schedule") as ss:
+            plan = self.scheduler.schedule(spec_drafts=drafts)
+        phases = {"propose": propose_s, "schedule_render": ss.s}
         if plan.num_tokens == 0:
             if self._pending is not None:      # drain the in-flight step
                 pend, self._pending = self._pending, None
-                pend.phases["propose"] += t1 - t0
+                for k, v in phases.items():
+                    pend.phases[k] += v
                 self._resolve(pend, None)
                 return 0
             # Idle iteration: nothing scheduled, nothing in flight — record
             # the wall time instead of letting it vanish from phase_s.
             self._metrics.record_step(
                 num_tokens=0, emitted_tokens=0, idle=True,
-                phases={"propose": t1 - t0,
-                        "idle": time.perf_counter() - t1})
+                phases={"propose": propose_s, "idle": ss.s})
             return 0
         if plan.spec:
             # Drafted steps are synchronization barriers: accepted drafts
@@ -617,8 +646,8 @@ class ServingEngine:
                 self._filter_finished(plan)
                 if plan.num_tokens == 0:
                     return 0
-            return self._step_sync(plan, t0, t1)
-        pend_new = self._build(plan, t0, t1)
+            return self._step_sync(plan, phases)
+        pend_new = self._build(plan, phases)
         prev, self._pending = self._pending, None
         if prev is not None:
             self._resolve(prev, pend_new)
@@ -710,7 +739,8 @@ class ServingEngine:
         self._drain_tier()
         self._drain_cow()
 
-    def _build(self, plan: StepPlan, t0: float, t1: float) -> "_PendingStep":
+    def _build(self, plan: StepPlan,
+               phases: Dict[str, float]) -> "_PendingStep":
         """Render + dispatch a draftless plan and commit it provisionally.
 
         Every lane's KV slots are reserved AND committed here (one token per
@@ -728,21 +758,25 @@ class ServingEngine:
         # serialize the overlap the async loop exists for.  The retrace
         # guard scopes only the fused dispatch — eager housekeeping
         # (fold_in, render uploads) compiles once harmlessly.
+        span = self._spans.span
         with self._sanitize_scope("overlap-build"):
-            lists, tokens, tok_src, sample_args, spec_args, committed = (
-                self._render(plan))
+            with span("render") as sr:
+                lists, tokens, tok_src, sample_args, spec_args, committed = (
+                    self._render(plan))
+                self._step_count += 1
+                key = jax.random.fold_in(self._key, self._step_count)
             assert spec_args is None, "drafted plans go through _step_sync"
-            self.sync_pools()
-            self._step_count += 1
-            key = jax.random.fold_in(self._key, self._step_count)
+            with span("drain") as sd:
+                self.sync_pools()
             nxt_prev = (self._pending.nxt_dev if self._pending is not None
                         else self._dummy_prev)
-            t2 = time.perf_counter()
             with self._expect_cached("step", lists, tokens, tok_src,
                                      nxt_prev, sample_args):
-                nxt_dev, self.pools = self._step_fn(
-                    self.params, self.pools, lists, tokens, tok_src,
-                    nxt_prev, key, *sample_args)
+                with span("dispatch") as sx:
+                    nxt_dev, self.pools = self._step_fn(
+                        self.params, self.pools, lists, tokens, tok_src,
+                        nxt_prev, key, *sample_args)
+        phases["schedule_render"] += sr.s + sd.s
         actions = []
         chain: Dict[int, int] = {}
         for req, n, pos0 in committed:
@@ -777,10 +811,9 @@ class ServingEngine:
         return _PendingStep(
             actions=actions,
             slots={req.req_id: req.slot for req, _, _ in committed},
-            chain=chain, nxt_dev=nxt_dev,
-            phases={"propose": t1 - t0,
-                    "schedule_render": t2 - t1},
-            num_tokens=plan.num_tokens, t_dispatch=t2)
+            chain=chain, nxt_dev=nxt_dev, phases=phases,
+            num_tokens=plan.num_tokens, lanes=_lanes(plan),
+            t_dispatch=sx.t0)
 
     def _resolve(self, pend: "_PendingStep",
                  next_pending: Optional["_PendingStep"]) -> None:
@@ -793,32 +826,35 @@ class ServingEngine:
         and the step's metrics are recorded with the device phase spanning
         dispatch -> future resolved.
         """
-        nxt = np.asarray(pend.nxt_dev)          # blocks until step N is done
-        t_done = time.perf_counter()
-        if self._chain is pend.chain:           # overlap off: nothing newer
-            self._chain = {}
-        now = time.time()
-        emitted = 0
-        for kind, req, n, pos0, out_idx in pend.actions:
-            rid = req.req_id
-            if rid in pend.cancelled or out_idx is None:
-                continue        # finished at an earlier resolve / chunk-only
-            tok = int(nxt[pend.slots[rid]])
-            req.output[out_idx] = tok
-            emitted += 1
-            preempted = req.state is RequestState.PREEMPTED
-            if kind == "decode" and not preempted:
-                self._register_generated(req, pos0, new_len=pos0 + n)
-            if kind == "prefill" and req.first_token_at is None:
-                req.first_token_at = now
-            # out_idx + 1 = this request's output length through THIS action
-            # (req.output may already hold the NEXT step's placeholder).
-            if out_idx + 1 >= req.max_new_tokens or tok == self.eos_id:
-                self._finish(req, now, next_pending=next_pending)
+        with self._spans.span("wait") as sw:
+            nxt = np.asarray(pend.nxt_dev)      # blocks until step N is done
+        with self._spans.span("commit") as sc:
+            if self._chain is pend.chain:       # overlap off: nothing newer
+                self._chain = {}
+            now = time.time()
+            emitted = 0
+            for kind, req, n, pos0, out_idx in pend.actions:
+                rid = req.req_id
+                if rid in pend.cancelled or out_idx is None:
+                    continue    # finished at an earlier resolve / chunk-only
+                tok = int(nxt[pend.slots[rid]])
+                req.output[out_idx] = tok
+                emitted += 1
+                preempted = req.state is RequestState.PREEMPTED
+                if kind == "decode" and not preempted:
+                    self._register_generated(req, pos0, new_len=pos0 + n)
+                if kind == "prefill" and req.first_token_at is None:
+                    req.first_token_at = now
+                # out_idx + 1 = this request's output length through THIS
+                # action (req.output may already hold the NEXT step's
+                # placeholder).
+                if out_idx + 1 >= req.max_new_tokens or tok == self.eos_id:
+                    self._finish(req, now, next_pending=next_pending)
         self._metrics.record_step(
             num_tokens=pend.num_tokens, emitted_tokens=emitted,
-            phases={**pend.phases, "device": t_done - pend.t_dispatch,
-                    "commit": time.perf_counter() - t_done})
+            **pend.lanes,
+            phases={**pend.phases, "device": sw.t1 - pend.t_dispatch,
+                    "commit": sc.s})
         # Post-reconciliation is the quiescent point: provisional commits,
         # finishes and preemption frees have all landed in the allocator.
         self._check_allocator()
@@ -834,24 +870,41 @@ class ServingEngine:
                         if r.state is RequestState.PREFILLING]
 
     # ------------------------------------------------------ synchronous step
-    def _step_sync(self, plan: StepPlan, t0: float, t1: float) -> int:
+    def _step_sync(self, plan: StepPlan, phases: Dict[str, float]) -> int:
         """The drafted (speculative) step, fully synchronous."""
-        lists, tokens, tok_src, sample_args, spec_args, committed = (
-            self._render(plan))
+        span = self._spans.span
+        with span("render") as sr:
+            lists, tokens, tok_src, sample_args, spec_args, committed = (
+                self._render(plan))
+            self._step_count += 1
+            key = jax.random.fold_in(self._key, self._step_count)
         assert spec_args is not None
         del tok_src                 # pipeline resolved: every token concrete
-        self.sync_pools()
-        self._step_count += 1
-        key = jax.random.fold_in(self._key, self._step_count)
-        t2 = time.perf_counter()
+        with span("drain") as sd:
+            self.sync_pools()
         with self._expect_cached("spec", lists, tokens, sample_args,
                                  spec_args):
-            out, acc, self.pools = self._spec_step_fn(
-                self.params, self.pools, lists, tokens, key, *sample_args,
-                *spec_args)
-        out, acc = np.asarray(out), np.asarray(acc)
+            with span("dispatch") as sx:
+                out, acc, self.pools = self._spec_step_fn(
+                    self.params, self.pools, lists, tokens, key,
+                    *sample_args, *spec_args)
+        with span("wait") as sw:
+            out, acc = np.asarray(out), np.asarray(acc)
+        with span("commit") as sc:
+            emitted = self._commit_spec(plan, committed, out, acc)
+        phases["schedule_render"] += sr.s + sd.s
+        self._metrics.record_step(
+            num_tokens=plan.num_tokens, emitted_tokens=emitted,
+            **_lanes(plan),
+            phases={**phases, "device": sw.t1 - sx.t0, "commit": sc.s})
+        self._check_allocator()
+        return plan.num_tokens
+
+    def _commit_spec(self, plan: StepPlan, committed, out: np.ndarray,
+                     acc: np.ndarray) -> int:
+        """Commit a drafted step's accepted tokens; returns how many were
+        emitted."""
         nxt = out[:, 0]
-        t3 = time.perf_counter()
         now = time.time()
         emitted = 0
         for req, n, _ in committed:
@@ -907,13 +960,7 @@ class ServingEngine:
         self._spec_counters["drafted_steps"] += 1
         self._spec_counters["proposed_tokens"] += sum(
             len(d) for d in plan.spec.values())
-        t4 = time.perf_counter()
-        self._metrics.record_step(
-            num_tokens=plan.num_tokens, emitted_tokens=emitted,
-            phases={"propose": t1 - t0, "schedule_render": t2 - t1,
-                    "device": t3 - t2, "commit": t4 - t3})
-        self._check_allocator()
-        return plan.num_tokens
+        return emitted
 
     def _register_generated(self, req: Request, pos0: int,
                             accepted: Optional[np.ndarray] = None,
@@ -993,6 +1040,7 @@ class ServingEngine:
     # --------------------------------------------------------------- metrics
     def metrics(self) -> Dict[str, float]:
         m = self._metrics.summary()
+        m["spans"] = self._spans.summary()
         hits, misses = self.alloc.prefix_hits, self.alloc.prefix_misses
         # Mesh attribution: the shape the fused program ran on (axis name ->
         # size; None for the single-device engine) and the device count, so

@@ -2,8 +2,9 @@
 (paper Fig 17e's axes) without storing every sample, plus the engine-level
 aggregate (:class:`EngineMetrics`) covering the scheduler-driven lifecycle:
 latency percentiles, throughput, preemption and prefix-cache counters,
-tokens-per-step, and per-step-phase wall-time buckets (propose / schedule /
-device / commit) so speculative-decoding overhead is visible without a
+tokens-per-step, prefill and decode lanes, and per-step-phase wall-time
+buckets (propose / schedule_render / device / commit, rolled up from the
+engine's host spans) so speculative-decoding overhead is visible without a
 profiler."""
 from __future__ import annotations
 
@@ -75,6 +76,10 @@ class EngineMetrics:
     steps: int = 0
     step_tokens: int = 0
     emitted_tokens: int = 0
+    # step_tokens by kind: prompt-chunk lanes and decoding requests (their
+    # sum is step_tokens on draftless steps; drafted lanes are in neither).
+    prefill_tokens: int = 0
+    decode_tokens: int = 0
     # Iterations where nothing was scheduled and nothing was in flight —
     # their wall time lands in phase_s["idle"] instead of vanishing, but
     # they don't count as steps (tokens-per-step keeps its meaning).
@@ -82,14 +87,18 @@ class EngineMetrics:
     phase_s: Dict[str, float] = field(default_factory=dict)
 
     def record_step(self, *, num_tokens: int, emitted_tokens: int,
-                    phases: Dict[str, float], idle: bool = False) -> None:
-        """One engine step: lane count, emitted output tokens, phase walls."""
+                    phases: Dict[str, float], idle: bool = False,
+                    prefill_tokens: int = 0, decode_tokens: int = 0) -> None:
+        """One engine step: lane count (and its prefill/decode split),
+        emitted output tokens, phase walls."""
         if idle:
             self.num_idle_steps += 1
         else:
             self.steps += 1
             self.step_tokens += num_tokens
             self.emitted_tokens += emitted_tokens
+            self.prefill_tokens += prefill_tokens
+            self.decode_tokens += decode_tokens
         for k, v in phases.items():
             self.phase_s[k] = self.phase_s.get(k, 0.0) + v
 
@@ -134,5 +143,7 @@ class EngineMetrics:
                                 if self.steps else 0.0),
             "lane_tokens_per_step": (self.step_tokens / self.steps
                                      if self.steps else 0.0),
+            "prefill_tokens": self.prefill_tokens,
+            "decode_tokens": self.decode_tokens,
             "phase_s": dict(self.phase_s),
         }

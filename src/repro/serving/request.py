@@ -94,6 +94,9 @@ class Request:
     priority: int = 0
     deadline: Optional[float] = None
     state: RequestState = RequestState.WAITING
+    # first admission (the scheduler's time.time(), the clock of arrival);
+    # a preempted request keeps it
+    admitted_at: Optional[float] = None
     first_token_at: Optional[float] = None
     done_at: Optional[float] = None
     output: List[int] = field(default_factory=list)

@@ -150,6 +150,8 @@ class Scheduler:
             slot = self.free_slots.pop()
             cached = self.alloc.allocate_prefix(req.req_id, active)
             req.begin_prefill(slot, cached, active_prompt=active)
+            if req.admitted_at is None:
+                req.admitted_at = now
             self.running[req.req_id] = req
             self.admission.on_admit(req, now)
 
