@@ -256,9 +256,10 @@ def ragged_lane_metadata(cu_q_lens, cu_kv_lens, seq_slot, num_lanes: int,
     """
     nseq = seq_slot.shape[0]
     lanes = jnp.arange(num_lanes, dtype=jnp.int32)
-    # rightmost j with cu_q_lens[j] <= lane: side="right" skips empty entries
-    j = jnp.searchsorted(cu_q_lens.astype(jnp.int32), lanes,
-                         side="right").astype(jnp.int32) - 1
+    # rightmost j with cu_q_lens[j] <= lane (the prefix sums are sorted, so
+    # a count of them; it skips empty entries)
+    cu = cu_q_lens.astype(jnp.int32)
+    j = (cu[None, :] <= lanes[:, None]).sum(axis=1, dtype=jnp.int32) - 1
     j = jnp.clip(j, 0, nseq - 1)
     nq = cu_q_lens[1:] - cu_q_lens[:-1]                  # (nseq,)
     kvl = cu_kv_lens[1:] - cu_kv_lens[:-1]               # (nseq,)
@@ -381,11 +382,12 @@ def paged_attention_ragged_op(q, kv_pool, block_list, block_req, block_pos,
     kernel in ``repro.kernels.paged_attention.kernel``.  The three kwargs are
     the family's registered tunables (docs/ragged_kernel.md):
     ``num_queries_per_block`` is the query-tile row count,
-    ``num_kv_pages_per_block`` how many KV pages one grid step consumes from
-    the double-buffered fused-page DMA ring, and ``vmem_limit_bytes`` caps
-    the ring's VMEM footprint (0 = uncapped).  jnp backends ignore all
-    three; measured best configs per (page_size, head_dim, backend) live in
-    the committed autotune table (``repro.perf.autotune``).
+    ``num_kv_pages_per_block`` how many KV pages one step of a query tile's
+    walk consumes from the double-buffered fused-page DMA ring, and
+    ``vmem_limit_bytes`` caps the ring's VMEM footprint (0 = uncapped).
+    jnp backends ignore all three; measured best configs per (page_size,
+    head_dim, backend) live in the committed autotune table
+    (``repro.perf.autotune``).
     """
     return dispatch.get_op("paged_attention_ragged")(
         q, kv_pool, block_list, block_req, block_pos, cu_q_lens, cu_kv_lens,

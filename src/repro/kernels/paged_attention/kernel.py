@@ -1,17 +1,20 @@
-"""PagedAttention decode kernel — the paper's BlockList technique, TPU-native.
+"""PagedAttention kernels — the paper's BlockList technique, TPU-native.
 
-The flat BlockList of *effectual* KV-block indices IS the Pallas grid: scalar
-prefetch (``pltpu.PrefetchScalarGridSpec``) feeds the block ids to the
-BlockSpec ``index_map``, so each grid step DMAs exactly one useful
-(block_size, KV, hd) tile from the HBM pool into VMEM. Zero-pad blocks never
-leave HBM — this is the TPU realization of vLLM_opt's "gather only effectual
-blocks" (paper Fig 16b), with the online-softmax accumulation replacing the
-separate Softmax launch.
+The flat BlockList of *effectual* KV-block indices drives the KV fetches:
+scalar prefetch (``pltpu.PrefetchScalarGridSpec``) puts the block ids in
+SMEM, and each fetch DMAs exactly one useful page from the HBM pool into
+VMEM. In the decode and chunked kernels the BlockList IS the Pallas grid
+(the BlockSpec ``index_map`` reads the block id); the ragged kernel's grid
+is the query tiles, and each tile walks only the BlockList range that
+holds its own sequences' entries, ringing its own page DMAs. Zero-pad
+blocks never leave HBM — this is the TPU realization of vLLM_opt's "gather
+only effectual blocks" (paper Fig 16b), with the online-softmax
+accumulation replacing the separate Softmax launch.
 
 The BlockList is sorted by request (the allocator guarantees it), so per-
 request accumulators live in VMEM scratch across the blocks of one request;
 output rows are rewritten as the running normalized value and the final
-grid step for a request leaves the correct result.
+page for a request leaves the correct result.
 """
 from __future__ import annotations
 
@@ -411,9 +414,37 @@ def paged_attention_chunked_pallas(q, pool_k, pool_v, block_list, block_req,
     return _ungroup_lanes(out, T, tq)
 
 
+def ragged_tile_ranges(block_req, cu_q_lens, seq_slot, num_tiles: int,
+                       tq: int):
+    """``(lo, hi)``, each (num_tiles,): the BlockList range query tile ``i``
+    walks.
+
+    Tile ``i`` holds lanes ``[i*tq, (i+1)*tq)``; its sequences are those
+    whose lanes ``[cu_q_lens[j], cu_q_lens[j+1])`` meet the tile, and its
+    range runs from the first to one past the last entry any of them owns
+    (``block_req`` == its slot).  Entries are laid out in lane order and
+    each sequence's are contiguous, so the range holds nothing else; it
+    covers every owned entry whatever the order, and anything caught inside
+    it is masked as before.  A tile with no sequence (padding lanes) gets
+    an empty range.
+    """
+    i32 = jnp.int32
+    tb = block_req.shape[0]
+    e = jnp.arange(tb, dtype=i32)[:, None]
+    owns = block_req[:, None] == seq_slot[None, :]              # (tb, nseq)
+    first = jnp.where(owns, e, tb).min(axis=0)                  # (nseq,)
+    last = jnp.where(owns, e + 1, 0).max(axis=0)
+    start = jnp.arange(num_tiles, dtype=i32)[:, None] * tq      # (n, 1)
+    cu = cu_q_lens.astype(i32)
+    meets = (cu[:-1] < start + tq) & (cu[1:] > start) & (cu[1:] > cu[:-1])
+    lo = jnp.where(meets, first, tb).min(axis=1)
+    hi = jnp.where(meets, last, 0).max(axis=1)
+    return jnp.minimum(lo, hi), hi
+
+
 def _ragged_kernel(
     # scalar-prefetched
-    block_list, block_req, block_pos, kv_lens,
+    block_list, block_req, block_pos, kv_lens, tile_lo, tile_hi,
     # blocked inputs (the fused pool stays in HBM/ANY — DMA'd manually)
     q_ref, kv_hbm, treq_ref, tpos_ref,
     # output
@@ -423,61 +454,76 @@ def _ragged_kernel(
     *, bs: int, num_kv: int, head_dim: int, num_reqs: int, sm_scale: float,
     pages: int, num_blocks: int,
 ):
-    """Ragged grid step over the FUSED pool.
+    """One query tile's walk of its own BlockList range, over the FUSED pool.
 
-    Grid is (num_q_tiles, num_page_groups): one step consumes ``pages``
-    BlockList entries against one ``num_queries_per_block``-row query tile.
-    The fused pool means ONE ``(KV, bs, 2*hd)`` page per DMA instead of a
-    (k, v) pair; its minor axis holds K and V side by side, so a
+    The grid is the query tiles; tile ``i`` walks entries
+    ``[tile_lo[i], tile_hi[i])`` (:func:`ragged_tile_ranges`) in a loop of
+    page groups, ``pages`` entries each, and fetches no page outside that
+    range.  The fused pool means ONE ``(KV, bs, 2*hd)`` page per DMA instead
+    of a (k, v) pair; its minor axis holds K and V side by side, so a
     head_dim-64 page moves whole 128-lane rows with the page size on the
     sublanes — the tiling the DMA engine needs.
-    The ring is double-buffered over page GROUPS: group ``t+1`` starts
-    before group ``t`` is waited, so a whole group's pages stream behind
-    the flash inner loop.  Pad entries fetch a real page and skip only the
-    compute, keeping every started copy waited exactly once.
+    The ring is double-buffered over page groups: group ``g+1`` starts
+    before group ``g`` is waited, so a whole group's pages stream behind
+    the flash inner loop.  Pad entries inside the range fetch a real page
+    and skip only the compute, keeping every started copy waited exactly
+    once.
 
     The per-page math is ``_flash_update`` + ``_chunked_valid_mask`` on the
     K and V lane halves of the fused page — the ragged and chunked paths
     cannot drift.
     """
-    t = pl.program_id(1)
-    Tg = pl.num_programs(1)
+    i = pl.program_id(0)
+    lo, hi = tile_lo[i], tile_hi[i]
+    num_groups = (hi - lo + pages - 1) // pages
     hd = head_dim
+
+    def copy(e, slot, j):
+        blk = jnp.minimum(block_list[e], num_blocks - 1)
+        return pltpu.make_async_copy(kv_hbm.at[blk], kv_buf.at[slot, j],
+                                     kv_sem.at[slot, j])
 
     def start_group(g):
         slot = jax.lax.rem(g, 2)
-        for j in range(pages):
-            blk = jnp.minimum(block_list[g * pages + j], num_blocks - 1)
-            pltpu.make_async_copy(kv_hbm.at[blk], kv_buf.at[slot, j],
-                                  kv_sem.at[slot, j]).start()
+        for j in range(pages):                    # static small loop
+            e = lo + g * pages + j
 
-    @pl.when(t == 0)
-    def _init():
-        _init_tile(acc_ref, m_ref, l_ref, o_ref)
-        start_group(jnp.int32(0))                 # warm-up: fill slot 0
+            @pl.when(e < hi)
+            def _start(e=e, j=j):
+                copy(e, slot, j).start()
 
-    @pl.when(t + 1 < Tg)                          # steady state: run ahead
-    def _ahead():
-        start_group(t + 1)
+    _init_tile(acc_ref, m_ref, l_ref, o_ref)
 
-    slot = jax.lax.rem(t, 2)
-    for j in range(pages):                        # static small loop
-        blk = jnp.minimum(block_list[t * pages + j], num_blocks - 1)
-        pltpu.make_async_copy(kv_hbm.at[blk], kv_buf.at[slot, j],
-                              kv_sem.at[slot, j]).wait()
-        e = t * pages + j
-        is_pad = block_req[e] >= num_reqs
+    @pl.when(num_groups > 0)                      # warm-up: fill slot 0
+    def _first():
+        start_group(jnp.int32(0))
 
-        @pl.when(jnp.logical_not(is_pad))
-        def _step(e=e, j=j):
-            valid = _chunked_valid_mask(block_req, block_pos, kv_lens,
-                                        treq_ref, tpos_ref, e, bs=bs,
-                                        num_reqs=num_reqs)
-            _flash_update(
-                q_ref, lambda kv: (kv_buf[slot, j, kv, :, :hd],
-                                   kv_buf[slot, j, kv, :, hd:]),
-                o_ref, acc_ref, m_ref, l_ref, valid, num_kv=num_kv,
-                sm_scale=sm_scale)
+    def group(g, carry):
+        @pl.when(g + 1 < num_groups)              # run ahead
+        def _ahead():
+            start_group(g + 1)
+
+        slot = jax.lax.rem(g, 2)
+        for j in range(pages):                    # static small loop
+            e = lo + g * pages + j
+
+            @pl.when(e < hi)
+            def _page(e=e, j=j):
+                copy(e, slot, j).wait()
+
+                @pl.when(block_req[e] < num_reqs)
+                def _step():
+                    valid = _chunked_valid_mask(
+                        block_req, block_pos, kv_lens, treq_ref, tpos_ref, e,
+                        bs=bs, num_reqs=num_reqs)
+                    _flash_update(
+                        q_ref, lambda kv: (kv_buf[slot, j, kv, :, :hd],
+                                           kv_buf[slot, j, kv, :, hd:]),
+                        o_ref, acc_ref, m_ref, l_ref, valid, num_kv=num_kv,
+                        sm_scale=sm_scale)
+        return carry
+
+    jax.lax.fori_loop(0, num_groups, group, 0)
 
 
 def paged_attention_ragged_pallas(q, kv_pool, block_list, block_req,
@@ -495,16 +541,18 @@ def paged_attention_ragged_pallas(q, kv_pool, block_list, block_req,
     minor axis), flat BlockList arrays (Tb,), and cu_q_lens/cu_kv_lens/
     seq_slot ragged metadata.  The lane arrays the grid masks against are
     DERIVED from the prefix sums at the XLA level (``ragged_lane_metadata``
-    — the same integer math as the jnp ref), then scalar-prefetched exactly
-    like the chunked kernel.
+    — the same integer math as the jnp ref), and so is each query tile's
+    BlockList range (:func:`ragged_tile_ranges`); both are scalar-prefetched.
+    The grid is the query tiles, and each walks only its own range.
 
     Tunables (registered on the ``paged_attention_ragged`` family, measured
     by the autotune sweep in ``benchmarks/paged_attention_bench.py``):
 
     * ``num_queries_per_block`` — query-tile rows per grid step (the ragged
       analogue of ``q_chunk``).
-    * ``num_kv_pages_per_block`` — fused KV pages one grid step consumes;
-      the double-buffered DMA ring holds ``2 *`` this many pages in VMEM.
+    * ``num_kv_pages_per_block`` — fused KV pages one step of a tile's walk
+      consumes; the double-buffered DMA ring holds ``2 *`` this many pages
+      in VMEM.
     * ``vmem_limit_bytes`` — cap on the ring's VMEM footprint: the page
       group is clamped so the ring fits, and the limit is passed to the
       Mosaic compiler.
@@ -515,7 +563,6 @@ def paged_attention_ragged_pallas(q, kv_pool, block_list, block_req,
     NB, num_kv, BS, hd2 = kv_pool.shape
     assert hd2 == 2 * hd, (kv_pool.shape, q.shape)
     B = seq_slot.shape[0]
-    Tb = block_list.shape[0]
     scale = float(sm_scale if sm_scale is not None else hd ** -0.5)
 
     token_req, token_pos, kv_lens = ragged_lane_metadata(
@@ -530,28 +577,24 @@ def paged_attention_ragged_pallas(q, kv_pool, block_list, block_req,
     qg, treq, tpos = _group_lanes(q, token_req, token_pos, num_kv, tq)
     n, _, R, _ = qg.shape
 
-    bpad = (-Tb) % pages
-    if bpad:
-        # Pad entries still fetch a (clamped) real page — only compute skips.
-        block_list = jnp.pad(block_list, (0, bpad))
-        block_req = jnp.pad(block_req, (0, bpad), constant_values=B)
-        block_pos = jnp.pad(block_pos, (0, bpad))
-    Tg = (Tb + bpad) // pages
+    # Each tile's BlockList range, once per launch; scalar-prefetched.
+    tile_lo, tile_hi = ragged_tile_ranges(block_req, cu_q_lens, seq_slot, n,
+                                          tq)
 
     kernel = functools.partial(
         _ragged_kernel, bs=BS, num_kv=num_kv, head_dim=hd, num_reqs=B,
         sm_scale=scale, pages=pages, num_blocks=NB)
 
     # index maps take (grid ids, *prefetched scalars)
-    def q_map(i, t, bl, br, bp, kvl):
+    def q_map(i, *_):
         return (i, 0, 0, 0)
 
-    def lane_map(i, t, bl, br, bp, kvl):
+    def lane_map(i, *_):
         return (i, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(n, Tg),
+        num_scalar_prefetch=6,
+        grid=(n,),
         in_specs=[
             pl.BlockSpec((1, num_kv, R, hd), q_map),
             # ONE buffer in HBM; the kernel rings its own fused-page DMAs.
@@ -569,11 +612,10 @@ def paged_attention_ragged_pallas(q, kv_pool, block_list, block_req,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
-        # The ring state spans grid steps of the q-tile dim too (warm-up
-        # reruns per tile), so neither dimension may be parallelized.
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
+            dimension_semantics=("arbitrary",),
             vmem_limit_bytes=int(vmem_limit_bytes) or None),
         interpret=interpret,
-    )(block_list, block_req, block_pos, kv_lens, qg, kv_pool, treq, tpos)
+    )(block_list, block_req, block_pos, kv_lens, tile_lo, tile_hi, qg,
+      kv_pool, treq, tpos)
     return _ungroup_lanes(out, T, tq)

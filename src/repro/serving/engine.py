@@ -487,13 +487,13 @@ class ServingEngine:
         # (same slot keys, same bucketing per shard slice): the fused
         # program shards them over the model axis and every rank attends
         # against exactly the BlockList slice its pool shard serves.
+        tables = {req.req_id: alloc.table(req.req_id)
+                  for req, _, _ in committed}
         if self.mesh is not None:
             bl, br, bp = alloc.build_sharded_block_lists(
                 [(req.req_id, req.slot) for req, _, _ in committed],
                 pad_req=Bs)
         else:
-            tables = {req.req_id: alloc.table(req.req_id)
-                      for req, _, _ in committed}
             needed = sum(len(t) for t in tables.values())
             cap = (self.max_total if needed <= self.max_total
                    else _bucket(needed, lo=self.max_total))
@@ -525,6 +525,18 @@ class ServingEngine:
         cu_kv = np.zeros((Bs + 1,), np.int32)
         cu_q[1:] = np.cumsum(q_lens)
         cu_kv[1:] = np.cumsum(kv_l)
+        # The ragged kernel's walk: each query tile of ``tq`` lanes walks the
+        # BlockList entries of the sequences its lanes belong to, from its
+        # first sequence's first entry to its last sequence's last.
+        cu_b = np.zeros((len(committed) + 1,), np.int64)
+        cu_b[1:] = np.cumsum([len(tables[req.req_id])
+                              for req, _, _ in committed])
+        tq = max(min(self.attn_tunables["num_queries_per_block"], T), 1)
+        starts = np.arange(0, cu_q[-1], tq)
+        ends = np.minimum(starts + tq, cu_q[-1]) - 1
+        first = np.searchsorted(cu_q, starts, "right") - 1
+        last = np.searchsorted(cu_q, ends, "right") - 1
+        attn_pages = int((cu_b[last + 1] - cu_b[first]).sum())
         lists = {
             "block_list": jnp.asarray(bl), "block_req": jnp.asarray(br),
             "block_pos": jnp.asarray(bp), "kv_lens": jnp.asarray(kv_lens),
@@ -543,7 +555,7 @@ class ServingEngine:
         spec_args = ((jnp.asarray(draft_tokens), jnp.asarray(draft_lens))
                      if spec_step else None)
         return (lists, jnp.asarray(tokens), jnp.asarray(tok_src),
-                sample_args, spec_args, committed)
+                sample_args, spec_args, committed, attn_pages)
 
     # -------------------------------------------------------------- main loop
     def _propose(self) -> Dict[int, np.ndarray]:
@@ -761,8 +773,8 @@ class ServingEngine:
         span = self._spans.span
         with self._sanitize_scope("overlap-build"):
             with span("render") as sr:
-                lists, tokens, tok_src, sample_args, spec_args, committed = (
-                    self._render(plan))
+                (lists, tokens, tok_src, sample_args, spec_args, committed,
+                 attn_pages) = self._render(plan)
                 self._step_count += 1
                 key = jax.random.fold_in(self._key, self._step_count)
             assert spec_args is None, "drafted plans go through _step_sync"
@@ -812,7 +824,8 @@ class ServingEngine:
             actions=actions,
             slots={req.req_id: req.slot for req, _, _ in committed},
             chain=chain, nxt_dev=nxt_dev, phases=phases,
-            num_tokens=plan.num_tokens, lanes=_lanes(plan),
+            num_tokens=plan.num_tokens,
+            lanes={**_lanes(plan), "attn_pages": attn_pages},
             t_dispatch=sx.t0)
 
     def _resolve(self, pend: "_PendingStep",
@@ -874,8 +887,8 @@ class ServingEngine:
         """The drafted (speculative) step, fully synchronous."""
         span = self._spans.span
         with span("render") as sr:
-            lists, tokens, tok_src, sample_args, spec_args, committed = (
-                self._render(plan))
+            (lists, tokens, tok_src, sample_args, spec_args, committed,
+             attn_pages) = self._render(plan)
             self._step_count += 1
             key = jax.random.fold_in(self._key, self._step_count)
         assert spec_args is not None
@@ -895,7 +908,7 @@ class ServingEngine:
         phases["schedule_render"] += sr.s + sd.s
         self._metrics.record_step(
             num_tokens=plan.num_tokens, emitted_tokens=emitted,
-            **_lanes(plan),
+            **_lanes(plan), attn_pages=attn_pages,
             phases={**phases, "device": sw.t1 - sx.t0, "commit": sc.s})
         self._check_allocator()
         return plan.num_tokens

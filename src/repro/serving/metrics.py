@@ -80,6 +80,9 @@ class EngineMetrics:
     # sum is step_tokens on draftless steps; drafted lanes are in neither).
     prefill_tokens: int = 0
     decode_tokens: int = 0
+    # BlockList entries the ragged kernel's query tiles walk, one layer's
+    # worth per step (each tile walks its own sequences' entries).
+    attn_pages: int = 0
     # Iterations where nothing was scheduled and nothing was in flight —
     # their wall time lands in phase_s["idle"] instead of vanishing, but
     # they don't count as steps (tokens-per-step keeps its meaning).
@@ -88,9 +91,10 @@ class EngineMetrics:
 
     def record_step(self, *, num_tokens: int, emitted_tokens: int,
                     phases: Dict[str, float], idle: bool = False,
-                    prefill_tokens: int = 0, decode_tokens: int = 0) -> None:
+                    prefill_tokens: int = 0, decode_tokens: int = 0,
+                    attn_pages: int = 0) -> None:
         """One engine step: lane count (and its prefill/decode split),
-        emitted output tokens, phase walls."""
+        emitted output tokens, BlockList entries walked, phase walls."""
         if idle:
             self.num_idle_steps += 1
         else:
@@ -99,6 +103,7 @@ class EngineMetrics:
             self.emitted_tokens += emitted_tokens
             self.prefill_tokens += prefill_tokens
             self.decode_tokens += decode_tokens
+            self.attn_pages += attn_pages
         for k, v in phases.items():
             self.phase_s[k] = self.phase_s.get(k, 0.0) + v
 
@@ -145,5 +150,6 @@ class EngineMetrics:
                                      if self.steps else 0.0),
             "prefill_tokens": self.prefill_tokens,
             "decode_tokens": self.decode_tokens,
+            "attn_pages": self.attn_pages,
             "phase_s": dict(self.phase_s),
         }

@@ -77,7 +77,8 @@ def test_engine_metrics_summary_keys_and_types():
                       "mean_tpot_s", "p50_tpot_s", "p90_tpot_s", "p99_tpot_s",
                       "throughput_tok_s", "steps", "num_idle_steps",
                       "tokens_per_step", "lane_tokens_per_step",
-                      "prefill_tokens", "decode_tokens", "phase_s"}
+                      "prefill_tokens", "decode_tokens", "attn_pages",
+                      "phase_s"}
     assert s["backend"] == "xla"
     assert s["finished"] == 2
     assert s["output_tokens"] == 10

@@ -80,6 +80,103 @@ def test_ragged_tunables_registered():
         assert np.array_equal(np.asarray(out), np.asarray(base)), (nq, nk)
 
 
+# ------------------------------------------------------- the kernel's walk
+# Each query tile walks only the BlockList range of its own sequences
+# (``ragged_tile_ranges``); these layouts put tiles, sequences and pads where
+# a range walk could go wrong.  seqs: (q_len, kv_len) per sequence, in lane
+# order; lanes: the step's (bucketed) lane count; tb: BlockList length.
+_WALK_LAYOUTS = {
+    # a 16-lane tile holding the end of one prompt chunk and the next's start
+    "straddle": dict(seqs=[(1, 40), (10, 30), (12, 12), (1, 70)], lanes=32,
+                     tb=64),
+    # one 2,048-token prefill: 128 tiles, each walking the same 128 pages
+    "long_prefill": dict(seqs=[(2048, 2048)], lanes=2048, tb=160),
+    # 20 live lanes in a 64-lane bucket: tiles 2 and 3 are padding only
+    "pad_tiles": dict(seqs=[(9, 9), (11, 50)], lanes=64, tb=32),
+    # the engine's list: padded to the whole pool, nearly all pads
+    "full_pool": dict(seqs=[(1, 33), (1, 17), (3, 48)], lanes=8, tb=3072),
+    # one decode lane per sequence, 32 sequences: two tiles
+    "decode": dict(seqs=[(1, 5 + 23 * j % 200) for j in range(32)],
+                   lanes=32, tb=512),
+    # decode lanes first, then a prefill chunk, as the engine renders them
+    "mixed": dict(seqs=[(1, 100), (1, 7), (1, 64), (45, 61), (19, 19)],
+                  lanes=128, tb=64),
+}
+_WIDTHS = {"hd64_g3": (15, 5, 64), "hd128_g6": (12, 2, 128)}
+
+
+def _walk_inputs(seqs, lanes, tb, H, KV, hd, *, num_slots=32, bs=16,
+                 shards=1, seed=0):
+    """Query lanes, a fused pool and the ragged metadata for ``seqs`` on
+    shuffled slots, with the BlockList rendered as the engine renders it
+    (lane order, each sequence's entries contiguous, pads at the end) —
+    or, with ``shards``, shard 0's LOCAL list and pool slice from
+    ``BlockAllocator.build_sharded_block_lists``."""
+    from repro.core.paged_kv import BlockAllocator
+    rng = np.random.default_rng(seed)
+    nb = tb * shards
+    alloc = BlockAllocator(num_blocks=nb, block_size=bs, num_shards=shards)
+    slots = rng.permutation(num_slots)[:len(seqs)]
+    for j, (_, kvl) in enumerate(seqs):
+        alloc.allocate(j, kvl)
+    pairs = [(j, int(slots[j])) for j in range(len(seqs))]
+    if shards > 1:
+        bl, br, bp = (x[0] for x in alloc.build_sharded_block_lists(
+            pairs, pad_req=num_slots, min_per_shard=tb))
+        nb = tb
+    else:
+        bl, br, bp, _ = alloc.build_block_list([j for j, _ in pairs], tb)
+        br = np.where(br < len(seqs), slots[np.minimum(br, len(seqs) - 1)],
+                      num_slots)
+    seq_slot = np.full((num_slots,), num_slots, np.int32)
+    seq_slot[:len(seqs)] = slots
+    cu_q = np.zeros((num_slots + 1,), np.int32)
+    cu_kv = np.zeros((num_slots + 1,), np.int32)
+    cu_q[1:len(seqs) + 1] = np.cumsum([q for q, _ in seqs])
+    cu_kv[1:len(seqs) + 1] = np.cumsum([k for _, k in seqs])
+    cu_q[len(seqs) + 1:] = cu_q[len(seqs)]
+    cu_kv[len(seqs) + 1:] = cu_kv[len(seqs)]
+    kq, kp = jax.random.split(jax.random.PRNGKey(seed))
+    q = jax.random.normal(kq, (lanes, H, hd), jnp.float32)
+    pool = jax.random.normal(kp, (nb, KV, bs, 2 * hd), jnp.float32)
+    return [q, pool] + [jnp.asarray(np.asarray(x, np.int32)) for x in
+                        (bl, br, bp, cu_q, cu_kv, seq_slot)]
+
+
+@pytest.mark.parametrize("width", sorted(_WIDTHS))
+@pytest.mark.parametrize("layout", sorted(_WALK_LAYOUTS) + ["sharded"])
+def test_ragged_kernel_range_walk_matches_ref(layout, width):
+    from repro.core.attention_api import paged_attention_ragged
+    from repro.kernels.paged_attention.kernel import (
+        paged_attention_ragged_pallas, ragged_tile_ranges)
+    H, KV, hd = _WIDTHS[width]
+    if layout == "sharded":
+        # shard 0's local list: some sequences have no entry on it, and the
+        # others' entries keep lane order, contiguous per sequence
+        args = _walk_inputs([(1, 90), (7, 40), (1, 33), (20, 20)], 32, 32,
+                            H, KV, hd, shards=2)
+    else:
+        args = _walk_inputs(**_WALK_LAYOUTS[layout], H=H, KV=KV, hd=hd)
+    q, _, bl, br, bp, cu_q, _, seq_slot = args
+    out = paged_attention_ragged_pallas(*args, num_queries_per_block=16,
+                                        interpret=True)
+    ref = paged_attention_ragged(*args)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5,
+                               atol=2e-5)
+    # every tile's range holds all of its sequences' entries and no entry
+    # of any other live sequence
+    n = -(-q.shape[0] // 16)
+    lo, hi = (np.asarray(x) for x in ragged_tile_ranges(br, cu_q, seq_slot,
+                                                        n, 16))
+    cu, slot_of, owner = np.asarray(cu_q), np.asarray(seq_slot), np.asarray(br)
+    for i in range(n):
+        mine = [int(slot_of[j]) for j in range(len(slot_of))
+                if cu[j] < cu[j + 1] and cu[j] < 16 * (i + 1)
+                and cu[j + 1] > 16 * i]
+        walked = set(owner[lo[i]:hi[i]].tolist()) - {len(slot_of)}
+        assert walked == set(mine) & set(owner.tolist()), (i, walked, mine)
+
+
 # ---------------------------------------------------------------- pool level
 def test_fused_pool_roundtrip_and_block_copy():
     NB, BS, KV, HD = 6, 4, 2, 8
@@ -156,6 +253,45 @@ def test_engine_ragged_vs_chunked_greedy_parity(serving_ref):
         assert outs == ref, (kw, outs, ref)
         assert m["attn_impl"] == "ragged"
     assert m_ref["attn_impl"] == "chunked"
+
+
+def test_attn_pages_counts_the_walked_ranges(serving_ref):
+    """The engine's ``attn_pages`` counter, computed on the host from the
+    tables it renders, equals the entries the kernel's tiles walk: the
+    summed ``hi - lo`` the step derives from the same BlockList."""
+    from repro.kernels.paged_attention.kernel import ragged_tile_ranges
+    cfg, model, params = serving_ref
+    tq = 4                              # small tiles: chunks straddle them
+    serve = ServeConfig(model=cfg.name, kv_block_size=4, max_batch=3,
+                        prefill_chunk=12, backend="ref",
+                        num_queries_per_block=tq)
+    eng = ServingEngine(model, params, cfg, serve, num_blocks=40)
+    rng = np.random.default_rng(3)
+    for i, n in enumerate([5, 19, 9, 14]):
+        eng.submit(Request(req_id=i, max_new_tokens=4, prompt=rng.integers(
+            0, cfg.vocab_size, (n,), dtype=np.int32)))
+    seen = []
+    step_fn = eng._step_fn
+
+    def spy(params, pools, lists, tokens, *rest):
+        seen.append((lists, tokens.shape[0]))
+        return step_fn(params, pools, lists, tokens, *rest)
+
+    eng._step_fn = spy
+    walked_steps = 0
+    while eng.busy:
+        before, n_seen = eng.metrics()["attn_pages"], len(seen)
+        eng.step()
+        if len(seen) == n_seen:
+            continue
+        lists, lanes = seen[-1]
+        t = min(tq, lanes)
+        lo, hi = ragged_tile_ranges(lists["block_req"], lists["cu_q_lens"],
+                                    lists["seq_slot"], -(-lanes // t), t)
+        walked = int(np.sum(np.asarray(hi) - np.asarray(lo)))
+        assert eng.metrics()["attn_pages"] - before == walked > 0
+        walked_steps += 1
+    assert walked_steps == len(seen) > 4
 
 
 @pytest.mark.slow

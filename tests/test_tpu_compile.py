@@ -72,6 +72,20 @@ def test_ragged_kernel_compiles_for_v5e(one_chip, H, KV, hd, pages):
     _compiles_kernel(one_chip, fn, *_ragged_shapes(H, KV, hd))
 
 
+@pytest.mark.parametrize("H,KV,hd", [(15, 5, 64), (12, 2, 128)])
+@pytest.mark.parametrize("lanes", [32, 4096])
+def test_ragged_walk_compiles_at_served_sizes(one_chip, H, KV, hd, lanes):
+    # smollm-360m's and qwen2-1.5b's heads, a decode step's 32 lanes and the
+    # largest prefill bucket, over a served pool and a BlockList of its size
+    i32, bf16, nb, slots = jnp.int32, jnp.bfloat16, 3072, 32
+    _compiles_kernel(
+        one_chip,
+        lambda *a: paged_attention_ragged_pallas(*a, interpret=False),
+        ((lanes, H, hd), bf16), ((nb, KV, BS, 2 * hd), bf16), ((nb,), i32),
+        ((nb,), i32), ((nb,), i32), ((slots + 1,), i32),
+        ((slots + 1,), i32), ((slots,), i32))
+
+
 def test_chunked_and_decode_kernels_compile_for_v5e(one_chip):
     H, KV, hd, i32, bf16 = 15, 5, 64, jnp.int32, jnp.bfloat16
     pool = ((NB, BS, KV, hd), bf16)
